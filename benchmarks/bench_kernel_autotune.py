@@ -1,13 +1,14 @@
 """Block-size autotune sweep for the layered-matmul Pallas kernel.
 
 Times ``layered_matmul_kernel_call`` over a small (bm, bn, bk) grid on a
-given problem shape and reports the fastest legal configuration.  On TPU
-the kernel runs compiled (Mosaic, megacore-parallel M/N grid); on CPU it
-runs in interpret mode, where the sweep validates the BlockSpecs and the
-relative block-count trade-offs rather than MXU throughput.
+given problem shape and reports the fastest legal configuration.  The
+kernel runs compiled (Mosaic, megacore-parallel M/N grid), which needs a
+TPU.  ``--interpret`` runs it in the Pallas interpreter instead, on any
+backend: that sweep validates the BlockSpecs and the relative block-count
+trade-offs, not MXU throughput.
 
 Run:  PYTHONPATH=src python benchmarks/bench_kernel_autotune.py \
-          --m 2 --d 7 --K 1024 --M 256 --N 256 --repeats 3
+          --m 2 --d 7 --K 1024 --M 256 --N 256 --repeats 3 [--interpret]
 """
 
 from __future__ import annotations
@@ -22,7 +23,6 @@ import jax
 import numpy as np
 
 from repro.kernels.layered_matmul import layered_matmul_kernel_call
-from repro.kernels.ops import default_interpret
 
 BM_SWEEP = (128, 256)
 BN_SWEEP = (128, 256)
@@ -81,12 +81,13 @@ def main(argv=None) -> int:
     ap.add_argument("--M", type=int, default=256)
     ap.add_argument("--N", type=int, default=256)
     ap.add_argument("--repeats", type=int, default=3)
-    ap.add_argument("--compiled", action="store_true",
-                    help="force compiled mode even off-TPU")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the kernel in the Pallas interpreter "
+                         "(any backend; no MXU timing)")
     ap.add_argument("--json", default=None, help="write sweep rows here")
     args = ap.parse_args(argv)
 
-    interpret = default_interpret() and not args.compiled
+    interpret = args.interpret
     mode = "interpret" if interpret else "compiled"
     print(f"layered_matmul autotune ({mode}): m={args.m} d={args.d} "
           f"K={args.K} M={args.M} N={args.N}")

@@ -28,8 +28,6 @@ from typing import Any, Callable, Optional
 import jax
 import numpy as np
 
-from repro.compat import tree_flatten_with_path
-
 __all__ = ["save", "restore", "latest_step", "AsyncCheckpointer",
            "install_sigterm_handler"]
 
@@ -52,7 +50,7 @@ def save(ckpt_dir: str, step: int, tree: Any) -> str:
     os.makedirs(ckpt_dir, exist_ok=True)
     final = os.path.join(ckpt_dir, f"step_{step:08d}")
     tmp = tempfile.mkdtemp(dir=ckpt_dir, prefix=".tmp_")
-    leaves, treedef = tree_flatten_with_path(tree)
+    leaves, treedef = jax.tree.flatten_with_path(tree)
     manifest = {"step": step, "leaves": []}
     for path, leaf in leaves:
         name = _leafname(path)
@@ -85,7 +83,7 @@ def restore(ckpt_dir: str, step: int, target_tree: Any,
     re-shards on load — elastic resume onto a different mesh.
     """
     d = os.path.join(ckpt_dir, f"step_{step:08d}")
-    leaves, treedef = tree_flatten_with_path(target_tree)
+    leaves, treedef = jax.tree.flatten_with_path(target_tree)
     shardings = (jax.tree.leaves(sharding_tree)
                  if sharding_tree is not None else [None] * len(leaves))
     out = []

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import layering
+
 try:
     from scipy.linalg import lu_factor, lu_solve
     _HAVE_SCIPY = True
@@ -321,6 +323,32 @@ class PolynomialCode:
         xp = np if isinstance(mat, np.ndarray) else jnp
         return xp.stack(xp.split(mat, nblocks, axis=1), axis=0)  # (n, K, M/n)
 
+    def _encode_host(self, mat: np.ndarray, nblocks: int,
+                     basis: np.ndarray) -> np.ndarray:
+        """``einsum("skn,st->tkn", split(mat), basis)`` in float64 as BLAS
+        products over slabs of rows.
+
+        Each slab is split and widened to float64 in one small reused
+        buffer, so a vocab-wide operand is never copied whole: the output
+        is the only large allocation.
+        """
+        K, M = mat.shape
+        if M % nblocks:
+            raise ValueError(f"second dim {M} not divisible by {nblocks}")
+        w = M // nblocks
+        T = basis.shape[1]
+        out = np.empty((T, K * w))
+        rows = max(1, layering.HOST_SLAB_ELEMS // max(M, 1))
+        buf = np.empty(nblocks * min(rows, K) * w)
+        for r0 in range(0, K, rows):
+            n = min(rows, K - r0)
+            blocks = buf[:nblocks * n * w].reshape(nblocks, n, w)
+            blocks[...] = mat[r0:r0 + n].reshape(n, nblocks, w).transpose(
+                1, 0, 2)
+            np.matmul(basis.T, blocks.reshape(nblocks, n * w),
+                      out=out[:, r0 * w:(r0 + n) * w])
+        return out.reshape(T, K, w)
+
     def encode_a(self, a: np.ndarray) -> np.ndarray:
         """Coded blocks ``X (T, K, M/n1)`` of operand A alone (host float64).
 
@@ -332,16 +360,14 @@ class PolynomialCode:
         if self.mode != "float":
             raise ValueError("encode_a is the float-mode host fast path")
         va, _ = _encode_basis(self)
-        blocks = self._split(a, self.n1)
-        return np.einsum("rkm,rt->tkm", blocks.astype(np.float64), va)
+        return self._encode_host(np.asarray(a), self.n1, va)
 
     def encode_b(self, b: np.ndarray) -> np.ndarray:
         """Coded blocks ``Y (T, K, N/n2)`` of operand B alone (host float64)."""
         if self.mode != "float":
             raise ValueError("encode_b is the float-mode host fast path")
         _, vb = _encode_basis(self)
-        blocks = self._split(b, self.n2)
-        return np.einsum("skn,st->tkn", blocks.astype(np.float64), vb)
+        return self._encode_host(np.asarray(b), self.n2, vb)
 
     def encode(self, a, b):
         """Returns coded task inputs ``X (T, K, M/n1)`` and ``Y (T, K, N/n2)``.

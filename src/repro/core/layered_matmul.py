@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import coding, layering
 
 __all__ = [
@@ -193,7 +192,7 @@ def distributed_layered_matmul(mesh: Mesh, axis: str, a: jax.Array,
         local = jnp.einsum("qtkm,qtkn->qtmn", x_blk, y_blk)
         return jax.lax.all_gather(local, axis, axis=1, tiled=True)
 
-    fn = shard_map(worker, mesh=mesh,
+    fn = jax.shard_map(worker, mesh=mesh,
                        in_specs=(P(None, axis), P(None, axis)),
                        out_specs=P(None, None))
     return fn(X, Y), [l for (l, _, _) in order]

@@ -37,7 +37,10 @@ __all__ = [
     "reconstruct",
     "quantize",
     "dequantize",
+    "exact_int_matmul",
     "layered_matmul_reference",
+    "layered_planes_reference",
+    "HOST_SLAB_ELEMS",
     "resolution_error_bound",
 ]
 
@@ -155,15 +158,58 @@ def dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
 # Reference layered matmul (the oracle every other implementation matches)
 # ---------------------------------------------------------------------------
 
+#: host passes over a vocab-wide operand (a 4096 x 64000 LM head) work in
+#: slabs of this many elements, 16 MiB of int64 or float64: a whole
+#: int64 or float64 copy of such an operand would be 2 GiB
+HOST_SLAB_ELEMS = 1 << 21
+
+
 def _np_decompose(x: np.ndarray, m: int, d: int) -> np.ndarray:
-    """NumPy twin of :func:`decompose` (int64 host arithmetic, always exact)."""
-    x = np.asarray(x, dtype=np.int64)
+    """NumPy twin of :func:`decompose` (host integer arithmetic, exact).
+
+    The planes keep an integer operand's own type (a shift and a mask never
+    widen a value); other operands are taken as int64.
+    """
+    x = np.asarray(x)
+    if not np.issubdtype(x.dtype, np.integer):
+        x = x.astype(np.int64)
     mask = (1 << d) - 1
-    chunks = []
+    # written in place: no temporaries beside the (m, *x.shape) result,
+    # which matters for vocab-wide operands
+    chunks = np.empty((m,) + x.shape, dtype=x.dtype)
     for i in range(m):
-        shifted = x >> (i * d)
-        chunks.append(shifted if i == m - 1 else shifted & mask)
-    return np.stack(chunks, axis=0)
+        np.right_shift(x, i * d, out=chunks[i])
+        if i < m - 1:
+            np.bitwise_and(chunks[i], mask, out=chunks[i])
+    return chunks
+
+
+def exact_int_matmul(x: np.ndarray, y: np.ndarray) -> np.ndarray:
+    """``x.T @ y`` of integer arrays (K, M), (K, N), exactly, as int64.
+
+    Runs in float64 BLAS when ``K * max|x| * max|y| < 2**53``: every
+    product and every partial sum is then an integer float64 holds
+    exactly, so the result is exact in any summation order.  Falls back
+    to int64 NumPy (no BLAS; about a hundred times slower at LM-head
+    widths) otherwise.
+    """
+    x = np.asarray(x)
+    y = np.asarray(y)
+    bound = x.shape[0] * _abs_max(x) * _abs_max(y)
+    if bound >= 2**53:
+        return x.T.astype(np.int64) @ y.astype(np.int64)
+    xt = x.T.astype(np.float64)
+    out = np.empty((x.shape[1], y.shape[1]), dtype=np.int64)
+    # y is widened to float64 a slab of columns at a time
+    cols = max(1, HOST_SLAB_ELEMS // max(y.shape[0], 1))
+    for c in range(0, y.shape[1], cols):
+        out[:, c:c + cols] = xt @ y[:, c:c + cols].astype(np.float64)
+    return out
+
+
+def _abs_max(x: np.ndarray) -> int:
+    # max|x| without an |x| temporary the size of x
+    return max(int(x.max(initial=0)), -int(x.min(initial=0)))
 
 
 def layered_matmul_reference(a, b, *, m: int, d: int) -> np.ndarray:
@@ -174,21 +220,30 @@ def layered_matmul_reference(a, b, *, m: int, d: int) -> np.ndarray:
     ``s >= 2m-2-l``, scaled by ``2**(s d)``).  ``resolutions[-1] == a.T @ b``
     exactly.
 
-    Host-side NumPy (int64) so exactness never depends on jax_enable_x64;
-    this is the oracle that the Pallas kernel and the jnp device path are
-    tested against.
+    Host-side NumPy (int64 results, via :func:`exact_int_matmul`) so
+    exactness never depends on jax_enable_x64; this is the oracle that
+    the Pallas kernel and the jnp device path are tested against.
     """
-    a = np.asarray(a)
-    b = np.asarray(b)
-    ca = _np_decompose(a, m, d)  # (m, K, M)
-    cb = _np_decompose(b, m, d)  # (m, K, N)
-    L = num_layers(m)
+    return layered_planes_reference(_np_decompose(a, m, d),
+                                    _np_decompose(b, m, d), d=d)
+
+
+def layered_planes_reference(ca: np.ndarray, cb: np.ndarray, *,
+                             d: int) -> np.ndarray:
+    """:func:`layered_matmul_reference` of operands already split into
+    integer digit planes ``ca (m, K, M)``, ``cb (m, K, N)``."""
+    m, K, M = ca.shape
+    # every A plane side by side: one product per B plane, so each
+    # vocab-wide B plane is widened for BLAS once rather than m times;
+    # prods[j][i] = ca[i].T @ cb[j]
+    a_all = ca.transpose(1, 0, 2).reshape(K, m * M)
+    prods = [exact_int_matmul(a_all, cb[j]).reshape(m, M, -1)
+             for j in range(m)]
     partials = []
-    for l in range(L):
-        acc = np.zeros((a.shape[1], b.shape[1]), dtype=np.int64)
+    for l in range(num_layers(m)):
+        acc = np.zeros((M, cb.shape[2]), dtype=np.int64)
         for (i, j) in layer_minijobs(m, l):
-            prod = ca[i].T.astype(np.int64) @ cb[j].astype(np.int64)
-            acc = acc + prod * (1 << ((i + j) * d))
+            acc += prods[j][i] << ((i + j) * d)
         partials.append(acc)
     return np.cumsum(np.stack(partials, axis=0), axis=0)
 

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU).
+"""Pallas TPU kernels (compiled for the TPU; CPU tests pass interpret=True).
 
   layered_matmul    the paper's mini-job grid as one fused MXU pass
   flash_attention   blockwise causal attention (prefill hot-spot)

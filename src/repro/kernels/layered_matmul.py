@@ -100,7 +100,7 @@ def layered_matmul_kernel_call(a_planes: jax.Array, b_planes: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((L, M, N), jnp.int32),
         # M/N output tiles are independent (megacore-parallel); the K axis
         # accumulates into the output tile and must stay sequential.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_planes, b_planes)

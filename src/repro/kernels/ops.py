@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on real
-TPUs, so the same call sites run everywhere; the kernels' BlockSpecs are
-written for TPU VMEM either way.
+The kernels are written for TPU VMEM and compile for the TPU by default.
+A caller on another backend (the CPU tests) passes ``interpret=True``
+explicitly; nothing switches to interpret mode on its own.
 """
 
 from __future__ import annotations
@@ -18,17 +18,13 @@ from repro.kernels.layered_matmul import layered_matmul_kernel_call
 from repro.kernels.ssd_scan import ssd_scan_kernel_call
 
 __all__ = ["layered_matmul", "layered_matmul_partials", "flash_attention",
-           "ssd_scan_fused", "default_interpret"]
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+           "ssd_scan_fused"]
 
 
 @functools.partial(jax.jit, static_argnames=("m", "d", "interpret"))
 def layered_matmul_partials(a: jax.Array, b: jax.Array, *, m: int = 2,
                             d: int = 7,
-                            interpret: bool | None = None) -> jax.Array:
+                            interpret: bool = False) -> jax.Array:
     """Exact int32 per-layer partials of ``a.T @ b`` (the worker compute).
 
     Decomposes integer a (K, M), b (K, N) into int8 digit planes (d <= 7 so
@@ -36,8 +32,6 @@ def layered_matmul_partials(a: jax.Array, b: jax.Array, *, m: int = 2,
     the unscaled layer-l partial sum -- exact as long as
     ``J(l) * K * (2^d - 1)^2 < 2^31``.
     """
-    if interpret is None:
-        interpret = default_interpret()
     if d > 7:
         raise ValueError("d <= 7 required for int8 digit planes")
     pa = layering.decompose(a.astype(jnp.int32), m, d).astype(jnp.int8)
@@ -51,7 +45,7 @@ def layered_matmul_partials(a: jax.Array, b: jax.Array, *, m: int = 2,
 
 @functools.partial(jax.jit, static_argnames=("m", "d", "interpret"))
 def layered_matmul(a: jax.Array, b: jax.Array, *, m: int = 2, d: int = 7,
-                   interpret: bool | None = None) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """Layered Definition-1 resolutions of ``a.T @ b``.
 
     Kernel partials + fp32 fusion (scale by ``2**((i+j) d)`` + cumulative
@@ -72,14 +66,12 @@ def layered_matmul(a: jax.Array, b: jax.Array, *, m: int = 2, d: int = 7,
                    static_argnames=("causal", "window", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
-                    interpret: bool | None = None) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Flash attention for (B, S, H, dh) tensors with GQA support.
 
     K/V may have fewer heads (n_kv); they are broadcast group-wise without
     materialising a repeat (reshape-only) before the kernel call.
     """
-    if interpret is None:
-        interpret = default_interpret()
     B, Sq, H, dh = q.shape
     _, Skv, n_kv, _ = k.shape
     G = H // n_kv
@@ -99,14 +91,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_fused(x: jax.Array, dt: jax.Array, A: jax.Array,
                    Bm: jax.Array, Cm: jax.Array, *, chunk: int = 256,
-                   interpret: bool | None = None):
+                   interpret: bool = False):
     """Fused-SSD twin of ``repro.models.ssm.ssd_scan`` (G = 1 only).
 
     x (B, S, H, P), dt (B, S, H), A (H,), Bm/Cm (B, S, 1, N) ->
     (y (B, S, H, P) fp32, final_state (B, H, P, N) fp32).
     """
-    if interpret is None:
-        interpret = default_interpret()
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     if S % chunk:

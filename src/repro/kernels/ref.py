@@ -17,20 +17,9 @@ def layered_matmul_ref(a_planes, b_planes, *, d: int) -> np.ndarray:
     Host NumPy, exact: the same Definition-1 cumulative anti-diagonal sums
     the kernel accumulates.
     """
-    a = np.asarray(a_planes, dtype=np.int64)
-    b = np.asarray(b_planes, dtype=np.int64)
-    m = a.shape[0]
-    L = layering.num_layers(m)
-    M, N = a.shape[2], b.shape[2]
-    out = np.zeros((L, M, N), dtype=np.float64)
-    running = np.zeros((M, N), dtype=np.float64)
-    for l in range(L):
-        for (i, j) in layering.layer_minijobs(m, l):
-            prod = a[i].T @ b[j]
-            running = running + prod.astype(np.float64) * float(
-                1 << ((i + j) * d))
-        out[l] = running
-    return out
+    return layering.layered_planes_reference(
+        np.asarray(a_planes, dtype=np.int64),
+        np.asarray(b_planes, dtype=np.int64), d=d).astype(np.float64)
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True,

@@ -15,23 +15,30 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_test_mesh", "batch_axes",
            "HardwareSpec", "TPU_V5E"]
 
 
+def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    # Auto axes: the model steps place activations with
+    # ``with_sharding_constraint`` (launch/axes.py), which Explicit axes --
+    # ``jax.make_mesh``'s default -- reject.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 1, model: int = 1, pod: int = 0) -> Mesh:
     """Small mesh over however many (host) devices the test owns."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _make_mesh((pod, data, model), ("pod", "data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
 
 
 def batch_axes(mesh: Mesh) -> tuple[str, ...]:

@@ -42,6 +42,7 @@ import sys
 import numpy as np
 
 from repro.core import simulator
+from repro.launch import compile_cache
 from repro.runtime import (BACKEND_NAMES, CODE_FAMILIES, FAULT_POLICIES,
                            FRAME_PROTOS, POLICIES, SHM_MODES,
                            RuntimeConfig, delay_table,
@@ -141,6 +142,7 @@ def summarize(cfg: RuntimeConfig, result) -> dict:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv and argv[0] == "serve-worker":
         # the remote half of the socket backend: run one worker host

@@ -14,7 +14,7 @@ Two budget modes, one release contract:
 * ``deadline_ms`` — the budget is wall-clock, and the step IS a runtime
   job: the head matmul ``hidden @ W`` is submitted to a
   :class:`~repro.runtime.gateway.ServingGateway` (thread-backend fleet,
-  one per batch shape) with the step's deadline and a guaranteed
+  started on first use) with the step's deadline and a guaranteed
   minimum of resolution 0, so all deadline logic — §IV termination,
   best-ready release, guaranteed-minimum rounds — flows through the
   runtime's own machinery rather than a serving-side controller.  Both
@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -39,6 +40,7 @@ import numpy as np
 from repro.configs import registry
 from repro.configs.base import ModelConfig
 from repro.core import progressive
+from repro.launch import compile_cache
 from repro.models import transformer as T
 from repro.runtime import RuntimeConfig, ServingGateway
 
@@ -65,24 +67,28 @@ class ServeStats:
 
 
 class _RuntimeHead:
-    """The LM head as runtime jobs: one warm thread-backend gateway per
-    batch shape, each decode step one deadline-bounded layered job.
+    """The LM head as runtime jobs: one warm thread-backend gateway,
+    each decode step one deadline-bounded layered job.
 
     ``hidden @ W`` is submitted as ``a.T @ b`` with ``a = hidden.T``
-    (so the coded split needs ``n1 | batch`` and ``n2 | vocab``), a
-    per-step absolute deadline, and ``min_resolution=0`` — the runtime
-    guarantees resolution 0 even past the deadline, the §IV
-    release-something contract the old plane controller hand-rolled.
+    (so the coded split needs ``n2 | vocab``), a per-step absolute
+    deadline, and ``min_resolution=0`` — the runtime guarantees
+    resolution 0 even past the deadline, the §IV release-something
+    contract the old plane controller hand-rolled.
+
+    Only W is split (``n1 = 1``): splitting the few-column ``hidden``
+    too would copy every coded W block ``n1`` times and raise the
+    recovery threshold ``k = n1 * n2``, and with it the decode's
+    condition number.
     """
 
-    def __init__(self, w: np.ndarray, m: int, d: int, batch: int):
+    def __init__(self, w: np.ndarray, m: int, d: int):
         vocab = w.shape[1]
-        n1 = next(n for n in (4, 2, 1) if batch % n == 0)
         n2 = next(n for n in (8, 4, 2, 1) if vocab % n == 0)
         cfg = RuntimeConfig(mu=(500.0, 500.0, 500.0), arrival_rate=1000.0,
-                            n1=n1, n2=n2, omega=1.0, m=m, d=d,
+                            n1=1, n2=n2, omega=1.0, m=m, d=d,
                             straggler="none", backend="thread")
-        self.w = np.asarray(w, np.float64)
+        self.w = np.asarray(w)
         self.num_layers = cfg.num_layers
         self.gateway = ServingGateway(cfg, admission="none").start()
 
@@ -112,7 +118,13 @@ class _RuntimeHead:
 
 
 class ProgressiveServer:
-    """Greedy batched decoding with a layered LM head."""
+    """Greedy batched decoding with a layered LM head.
+
+    ``hidden_step(params, token, caches, pos)`` (jitted) is one decode step
+    up to the final norm, returning ``(hidden (B, D), caches)``;
+    ``head_series(hidden)`` (jitted) is the on-chip head's ``m``
+    MSB-first resolutions, ``(m, B, V)`` float32.
+    """
 
     def __init__(self, cfg: ModelConfig, params: dict, *, m: int = 2,
                  d: int = 7):
@@ -124,7 +136,7 @@ class ProgressiveServer:
         self._head_w = w
         self.m = m
         self.d = d
-        self._runtime_heads: dict[int, _RuntimeHead] = {}
+        self._runtime_head: Optional[_RuntimeHead] = None
 
         def hidden_step(params, token, caches, pos):
             """decode_step but returning final hidden state, not logits."""
@@ -172,23 +184,18 @@ class ProgressiveServer:
                 return x[:, 0, :], (new_caches, enc_kvs)
             return x[:, 0, :], new_caches
 
-        self._hidden_step = jax.jit(hidden_step)
-        self._head_series = jax.jit(
-            lambda h: progressive.resolution_series(self.lm_head,
-                                                    h.astype(jnp.float32)))
-
-    def _runtime_head(self, batch: int) -> _RuntimeHead:
-        head = self._runtime_heads.get(batch)
-        if head is None:
-            head = _RuntimeHead(np.asarray(self._head_w), self.m, self.d,
-                                batch)
-            self._runtime_heads[batch] = head
-        return head
+        self.hidden_step = jax.jit(hidden_step)
+        # the head's planes are an argument, not a closure: captured, they
+        # would be baked into the program as constants (half a GB at a
+        # 4096 x 64000 head)
+        series = jax.jit(lambda lin, h: progressive.resolution_series(
+            lin, h.astype(jnp.float32)))
+        self.head_series = functools.partial(series, self.lm_head)
 
     def close(self) -> None:
-        """Stop every runtime-head gateway fleet (idempotent)."""
-        heads, self._runtime_heads = self._runtime_heads, {}
-        for head in heads.values():
+        """Stop the runtime-head gateway fleet (idempotent)."""
+        head, self._runtime_head = self._runtime_head, None
+        if head is not None:
             head.close()
 
     def __enter__(self) -> "ProgressiveServer":
@@ -224,13 +231,15 @@ class ProgressiveServer:
         out = []
         for i in range(num_tokens):
             pos = jnp.int32(start_pos + i)
-            hidden, caches = self._hidden_step(self.params, tok, caches, pos)
+            hidden, caches = self.hidden_step(self.params, tok, caches, pos)
             if deadline_ms is not None:
                 # the step is a runtime job: deadline release, best-ready
                 # resolution, and the guaranteed res-0 minimum all come
                 # from the runtime's §IV machinery
-                head = self._runtime_head(int(hidden.shape[0]))
-                logits_np, rel, svc = head.step(
+                if self._runtime_head is None:
+                    self._runtime_head = _RuntimeHead(
+                        np.asarray(self._head_w), self.m, self.d)
+                logits_np, rel, svc = self._runtime_head.step(
                     np.asarray(hidden, np.float64), deadline_ms / 1e3)
                 release = rel + 1
                 stats.head_service_seconds.append(svc)
@@ -238,7 +247,7 @@ class ProgressiveServer:
             else:
                 release = (self.m if layer_budget is None
                            else max(1, min(layer_budget, self.m)))
-                series = self._head_series(hidden)     # (m, B, V)
+                series = self.head_series(hidden)      # (m, B, V)
                 logits = series[release - 1]
             stats.steps += 1
             stats.full_resolution += int(release == stats.resolutions)
@@ -249,6 +258,7 @@ class ProgressiveServer:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="llama3-8b-smoke")
     ap.add_argument("--batch", type=int, default=4)

@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.runtime import RuntimeConfig, ServingGateway
 from repro.runtime.tasks import BACKEND_NAMES
 
@@ -83,6 +84,7 @@ def _print_summary(stats) -> None:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     argv = list(sys.argv[1:] if argv is None else argv)
     ap = argparse.ArgumentParser(
         prog="runctl serve-gateway", description=__doc__,

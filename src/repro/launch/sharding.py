@@ -25,7 +25,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import tree_flatten_with_path
 from repro.launch.mesh import batch_axes
 
 __all__ = ["param_specs", "opt_state_specs", "batch_specs", "cache_specs_tree",
@@ -167,7 +166,7 @@ def param_specs(params_shapes: Any, mesh: Mesh,
     profile "serve" drops the FSDP axis (weights stay TP-sharded,
     replicated over data): serving must not re-gather weights per token.
     """
-    flat, treedef = tree_flatten_with_path(params_shapes)
+    flat, treedef = jax.tree.flatten_with_path(params_shapes)
     specs = [_spec_for(p, l, mesh) for p, l in flat]
     if profile == "serve":
         specs = [P(*(None if ax == _FSDP else ax for ax in tuple(sp)))
@@ -182,7 +181,7 @@ def opt_state_specs(opt_shapes: Any, pspecs: Any, mesh: Mesh) -> Any:
     dim) and "vc" (param minus second-to-last) drop that entry of the spec;
     scalars (step/gnorm/lr) replicate.
     """
-    pflat, _ = tree_flatten_with_path(pspecs,
+    pflat, _ = jax.tree.flatten_with_path(pspecs,
                                       is_leaf=lambda x: isinstance(x, P))
     by_path = {tuple(_leaf_name_seq(p)): s for p, s in pflat}
 
@@ -210,7 +209,7 @@ def opt_state_specs(opt_shapes: Any, pspecs: Any, mesh: Mesh) -> Any:
             spec = spec[: len(_shape_of(leaf))]
         return fix_spec(_shape_of(leaf), spec, mesh)
 
-    flat, treedef = tree_flatten_with_path(opt_shapes)
+    flat, treedef = jax.tree.flatten_with_path(opt_shapes)
     return treedef.unflatten([spec_of(p, l) for p, l in flat])
 
 
@@ -253,7 +252,7 @@ def cache_specs_tree(cache_shapes: Any, mesh: Mesh) -> Any:
     Distinguishing k/v from state: state is fp32 and named "state".
     """
     baxes = batch_axes(mesh)
-    flat, treedef = tree_flatten_with_path(cache_shapes)
+    flat, treedef = jax.tree.flatten_with_path(cache_shapes)
 
     def _first_legal(shape, candidates):
         """First candidate whose named axes all survive fix_spec."""

@@ -28,6 +28,7 @@ from repro.configs import registry
 from repro.configs.base import (AttentionConfig, ModelConfig, ShapeConfig,
                                 TrainConfig)
 from repro.data.pipeline import SyntheticLM
+from repro.launch import compile_cache
 from repro.launch import sharding as sh
 from repro.launch import steps as steps_lib
 from repro.launch.mesh import make_test_mesh
@@ -121,6 +122,7 @@ def train_loop(cfg: ModelConfig, tcfg: TrainConfig, *, batch: int, seq: int,
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="quickstart-100m",
                     help="arch id, '<id>-smoke', or 'quickstart-100m'")

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import layering
 
 __all__ = ["plane_split", "plane_reconstruct", "layered_psum",
@@ -95,7 +94,7 @@ def layered_allreduce_tree(grads, mesh: Mesh, axis: str, *, m: int = 2,
             planes = layered_psum(planes, axis)
             return plane_reconstruct(planes, scale, d, resolution) / n
 
-        return shard_map(inner, mesh=mesh, in_specs=P(axis),
+        return jax.shard_map(inner, mesh=mesh, in_specs=P(axis),
                              out_specs=P(axis))(g)
 
     return jax.tree.map(per_leaf, grads)

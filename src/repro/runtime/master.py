@@ -90,7 +90,10 @@ def make_jobs(cfg: RuntimeConfig, num_jobs: int, *, K: int = 64, M: int = 8,
     """Random integer-matrix jobs with Poisson (or trace) arrivals.
 
     Operand magnitudes stay well inside ``m * d`` bits so float-mode decode
-    is tight; ``M``/``N`` must be divisible by ``n1``/``n2``.
+    is tight; ``M``/``N`` must be divisible by ``n1``/``n2``.  Operands are
+    stored in the narrowest integer type that holds them (int16 at the
+    default m = 2, d = 7): every job exists before the first one runs, and
+    a vocab-wide int64 operand is 2 GiB.  Widen them before multiplying.
     """
     rng = rng if rng is not None else np.random.default_rng(cfg.seed)
     if arrivals is None:
@@ -100,9 +103,21 @@ def make_jobs(cfg: RuntimeConfig, num_jobs: int, *, K: int = 64, M: int = 8,
     if len(arrivals) != num_jobs:
         raise ValueError(f"{len(arrivals)} arrivals for {num_jobs} jobs")
     lim = 1 << (cfg.m * cfg.d - 2)
-    return [JobSpec(job_id=j,
-                    a=rng.integers(-lim, lim, size=(K, M), dtype=np.int64),
-                    b=rng.integers(-lim, lim, size=(K, N), dtype=np.int64),
+    dtype = np.min_scalar_type(-lim)
+
+    def operand(shape):
+        # drawn as int64 so the values do not depend on the stored type,
+        # a few rows at a time (the same values as one draw) so that no
+        # int64 copy of a vocab-wide operand is ever made
+        out = np.empty(shape, dtype)
+        rows = max(1, layering.HOST_SLAB_ELEMS // max(shape[1], 1))
+        for r in range(0, shape[0], rows):
+            slab = out[r:r + rows]
+            slab[...] = rng.integers(-lim, lim, size=slab.shape,
+                                     dtype=np.int64)
+        return out
+
+    return [JobSpec(job_id=j, a=operand((K, M)), b=operand((K, N)),
                     arrival=float(arrivals[j]))
             for j in range(num_jobs)]
 
@@ -283,19 +298,23 @@ class Master:
 
     # -- operand preparation -------------------------------------------------
     def _prepare(self, job: JobSpec):
-        """Quantize float operands, digit-decompose both into m planes."""
+        """Quantize float operands, digit-decompose both into m planes.
+
+        The integer operands keep their own width (the planes are int64):
+        a vocab-wide int64 copy is 2 GiB, held for the whole job.
+        """
         cfg = self.cfg
         bits = cfg.m * cfg.d
         if np.issubdtype(np.asarray(job.a).dtype, np.floating):
             qa, sa = layering.quantize(jnp.asarray(job.a), bits)
-            qa, sa = np.asarray(qa, np.int64), float(sa)
+            qa, sa = np.asarray(qa), float(sa)
         else:
-            qa, sa = np.asarray(job.a, np.int64), 1.0
+            qa, sa = np.asarray(job.a), 1.0
         if np.issubdtype(np.asarray(job.b).dtype, np.floating):
             qb, sb = layering.quantize(jnp.asarray(job.b), bits)
-            qb, sb = np.asarray(qb, np.int64), float(sb)
+            qb, sb = np.asarray(qb), float(sb)
         else:
-            qb, sb = np.asarray(job.b, np.int64), 1.0
+            qb, sb = np.asarray(job.b), 1.0
         ca = layering._np_decompose(qa, cfg.m, cfg.d)   # (m, K, M)
         cb = layering._np_decompose(qb, cfg.m, cfg.d)   # (m, K, N)
         return qa, qb, sa * sb, ca, cb
@@ -304,8 +323,8 @@ class Master:
         """Run one encode/compute/decode off the clock (BLAS/cache warm)."""
         code = self.controller.code
         _, _, _, ca, cb = self._prepare(job)
-        X = code.encode_a(np.asarray(ca[0], np.float64))
-        Y = code.encode_b(np.asarray(cb[0], np.float64))
+        X = code.encode_a(ca[0])
+        Y = code.encode_b(cb[0])
         code.decode(list(range(code.k)),
                     np.stack([X[t].T @ Y[t] for t in range(code.k)]))
 
@@ -384,12 +403,10 @@ class Master:
                 _, pi, pj = rounds[lvl]
                 Xa = enc_a.get((T, pi))
                 if Xa is None:
-                    Xa = enc_a[(T, pi)] = lcode.encode_a(
-                        np.asarray(ca[pi], np.float64))
+                    Xa = enc_a[(T, pi)] = lcode.encode_a(ca[pi])
                 Yb = enc_b.get((T, pj))
                 if Yb is None:
-                    Yb = enc_b[(T, pj)] = lcode.encode_b(
-                        np.asarray(cb[pj], np.float64))
+                    Yb = enc_b[(T, pj)] = lcode.encode_b(cb[pj])
                 ctxs.append(RoundContext(job.job_id, ridx0 + lvl))
                 Xs.append(Xa)
                 Ys.append(Yb)
@@ -677,12 +694,10 @@ class Master:
                         T = rcode.num_tasks
                         Xa = enc_a.get((T, pi))
                         if Xa is None:
-                            Xa = enc_a[(T, pi)] = rcode.encode_a(
-                                np.asarray(ca[pi], np.float64))
+                            Xa = enc_a[(T, pi)] = rcode.encode_a(ca[pi])
                         Yb = enc_b.get((T, pj))
                         if Yb is None:
-                            Yb = enc_b[(T, pj)] = rcode.encode_b(
-                                np.asarray(cb[pj], np.float64))
+                            Yb = enc_b[(T, pj)] = rcode.encode_b(cb[pj])
                         te = clock()
                         stage["encode"] += te - ts
                         if tr is not None:
@@ -862,6 +877,11 @@ class Master:
                         pending = (rf, ridx, l, pi, pj, rcode)
                     if pending is not None:   # drain the decode-behind stage
                         finish_round(*pending)
+                    # the coded planes die with the job, before its verify
+                    # and the next job's encodes
+                    enc_a.clear()
+                    enc_b.clear()
+                    nxt = None
                 end = clock()
                 lr.release(terminated=term)
                 if tr is not None:
@@ -885,8 +905,8 @@ class Master:
                 lc_rows.append(lc)
                 ok_rows.append(ok)
                 if self.verify:
-                    ref = layering.layered_matmul_reference(
-                        qa, qb, m=cfg.m, d=cfg.d).astype(np.float64) * scale
+                    ref = layering.layered_planes_reference(
+                        ca, cb, d=cfg.d).astype(np.float64) * scale
                     ver = np.full(L, np.nan)
                     for l in range(L):
                         if lr.resolution_ready(l):
@@ -895,6 +915,9 @@ class Master:
                                 np.abs(lr.resolution(l) - ref[l]).max()
                                 / denom)
                     ver_rows.append(ver)
+                # the loop may now block on the next arrival: drop this
+                # job's operands and planes first
+                del prep, qa, qb, ca, cb
         finally:
             pool.shutdown()
 

@@ -64,8 +64,10 @@ class RuntimeResult(simulator.SimResult):
                          legacy-flag upgrade, for bench/JSON provenance.
     ``transport_stats``  wire-level counters for transports that cross a
                          network (socket backend: frames, dispatch/result
-                         raw-vs-wire bytes, compression ratio); None for
-                         in-process backends.
+                         raw-vs-wire bytes, compression ratio); for the
+                         jax backend, results per computing device
+                         (``result_devices``); None for the thread
+                         backend.
     ``tasks_done``       coded tasks computed and emitted across all
                          workers (exact: collected post-shutdown).
     ``tasks_purged``     tasks reclaimed by purges before completion.

@@ -144,8 +144,9 @@ class WorkerTransport(abc.ABC):
     #: or network boundary override this (as a property) with a dict of
     #: plain counters — frames/bytes per path, serialization-copied vs
     #: zero-copy splits; the master surfaces it as
-    #: ``RuntimeResult.transport_stats``.  Purely in-process backends
-    #: (thread, jax) have no wire and leave it ``None``.
+    #: ``RuntimeResult.transport_stats``.  The thread backend has no wire
+    #: and leaves it ``None``; the jax backend reports the devices that
+    #: computed its results instead.
     wire_stats: Optional[dict] = None
 
     def __init__(self, cfg: RuntimeConfig,

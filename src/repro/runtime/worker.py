@@ -88,34 +88,45 @@ def _host_compute(x: np.ndarray, y: np.ndarray,
     return np.matmul(x.T, y, out=out)
 
 
-def _jax_compute(device) -> Callable[[np.ndarray, np.ndarray], np.ndarray]:
+def _jax_compute(device, placed: Optional[collections.Counter]
+                 ) -> Callable[[np.ndarray, np.ndarray], np.ndarray]:
     import jax
     import jax.numpy as jnp
 
-    fn = jax.jit(lambda x, y: jnp.matmul(x.T, y))
+    # HIGHEST keeps the float32 products the decode was written for: the
+    # any-k decode is a Vandermonde solve that multiplies the products'
+    # error by the code's condition number, and a TPU's default matmul
+    # precision is a single bfloat16 pass.
+    fn = jax.jit(lambda x, y: jnp.matmul(
+        x.T, y, precision=jax.lax.Precision.HIGHEST))
 
     def compute(x: np.ndarray, y: np.ndarray) -> np.ndarray:
         # dispatch is asynchronous (jit returns immediately); the
         # np.asarray materialization is the only synchronization point,
         # right before the result is emitted to the fusion node.
         out = fn(jax.device_put(x, device), jax.device_put(y, device))
+        if placed is not None:
+            placed.update(f"{d.platform}:{d.id}" for d in out.devices())
         return np.asarray(out)
 
     return compute
 
 
-def make_compute(cfg: RuntimeConfig, worker_id: int, *, device=None
+def make_compute(cfg: RuntimeConfig, worker_id: int, *, device=None,
+                 placed: Optional[collections.Counter] = None
                  ) -> Callable[[np.ndarray, np.ndarray], np.ndarray]:
     """The coded-task kernel for one worker: host BLAS or a JAX device.
 
     ``device`` pins the worker to a specific JAX device (the ``jax``
     backend passes ``jax.devices()[worker_id % len(devices)]``); with
     ``device=None`` the worker computes on host BLAS, which releases the
-    GIL so a thread pool genuinely overlaps.
+    GIL so a thread pool genuinely overlaps.  ``placed``, with a device,
+    counts each result under the device that holds it
+    (``"<platform>:<id>"``); only the calling worker's thread writes it.
     """
     del worker_id  # reserved for per-worker kernel variants
     if device is not None:
-        return _jax_compute(device)
+        return _jax_compute(device, placed)
     return _host_compute
 
 
@@ -380,13 +391,9 @@ class WorkerPool(WorkerTransport):
         self._shutting_down = False
 
     def _compute_for(self, worker_id: int):
-        """Kernel factory hook; the jax backend overrides with devices."""
-        device = None
-        if self._cfg.use_jax_devices:
-            import jax
-            devices = jax.devices()
-            device = devices[worker_id % len(devices)]
-        return make_compute(self._cfg, worker_id, device=device)
+        """Kernel factory hook: host BLAS.  The jax backend (which the
+        legacy ``use_jax_devices`` flag selects) overrides it with devices."""
+        return make_compute(self._cfg, worker_id)
 
     def start(self) -> None:
         for w in self.workers:

@@ -44,6 +44,24 @@ class TestPolynomialCodeFloat:
             dec = np.asarray(code.decode(ids, tasks[np.asarray(ids)]))
             np.testing.assert_allclose(dec, exact, rtol=2e-2, atol=5e-3)
 
+    @pytest.mark.parametrize("n,K,width", [(2, 7, 3), (8, 70, 4096),
+                                           (3, 5, 1)])
+    def test_host_encode_matches_einsum(self, rng, n, K, width):
+        """The slab-wise BLAS encode equals the plain einsum encode; the
+        (8, 70, 4096) case spans three row slabs, the last one partial.
+        Tolerance: sums of n float64 products, a few ulps of the largest
+        term."""
+        code = coding.PolynomialCode(n1=n, n2=n, omega=1.5, mode="float")
+        va, vb = coding._encode_basis(code)
+        a = rng.integers(-127, 128, size=(K, n * width)).astype(np.int16)
+        for got, basis in ((code.encode_a(a), va), (code.encode_b(a), vb)):
+            want = np.einsum("skn,st->tkn",
+                             code._split(a, n).astype(np.float64), basis)
+            assert got.shape == want.shape == (code.num_tasks, K, width)
+            scale = 127 * np.abs(basis).sum(axis=0).max()
+            np.testing.assert_allclose(got, want, rtol=0,
+                                       atol=8 * n * scale * 2.0**-52)
+
     def test_insufficient_results_raise(self, rng):
         code = coding.PolynomialCode(n1=2, n2=2, omega=1.5)
         with pytest.raises(ValueError):

@@ -79,6 +79,13 @@ class TestLayeredMatmulKernel:
                                jnp.zeros((8, 8), jnp.int32), m=2, d=8,
                                interpret=True)
 
+    def test_compiled_by_default_no_cpu_fallback(self):
+        """Without ``interpret=True`` the kernel compiles for the TPU; on
+        the CPU that fails instead of silently interpreting."""
+        with pytest.raises(ValueError, match="interpret"):
+            ops.layered_matmul_partials(jnp.zeros((512, 128), jnp.int32),
+                                        jnp.zeros((512, 128), jnp.int32))
+
 
 class TestFlashAttentionKernel:
     @pytest.mark.parametrize("B,S,H,kv,dh,causal,window,dtype", [

@@ -111,6 +111,49 @@ class TestLayeredMatmul:
             bound = layering.resolution_error_bound(m, d, K, l)
             assert np.abs(res[l] - exact).max() <= bound
 
+    @pytest.mark.parametrize("bits", [7, 20, 24, 28])
+    def test_exact_int_matmul_matches_int64(self, rng, bits):
+        """Exact on both sides of the float64 threshold: K * 2^(2*bits)
+        is below 2**53 for 7 and 20 bits (BLAS path), above it for 24
+        and 28 (int64 path; still no int64 overflow)."""
+        K = 64
+        A = rng.integers(-(1 << bits), 1 << bits, size=(K, 5))
+        B = rng.integers(-(1 << bits), 1 << bits, size=(K, 7))
+        got = layering.exact_int_matmul(A, B)
+        assert got.dtype == np.int64
+        np.testing.assert_array_equal(got, A.T @ B)
+
+    def test_exact_int_matmul_wide_int16(self, rng):
+        """Operands wider than one float64 slab, in their own int16 type:
+        every column slab lands in place."""
+        K = 64
+        N = 2 * layering.HOST_SLAB_ELEMS // K + 3
+        A = rng.integers(-(1 << 12), 1 << 12, size=(K, 3)).astype(np.int16)
+        B = rng.integers(-(1 << 12), 1 << 12, size=(K, N)).astype(np.int16)
+        got = layering.exact_int_matmul(A, B)
+        np.testing.assert_array_equal(
+            got, A.T.astype(np.int64) @ B.astype(np.int64))
+
+    @pytest.mark.parametrize("m,d", [(1, 8), (2, 7), (3, 6)])
+    def test_planes_reference_matches_minijob_loop(self, rng, m, d):
+        """The per-B-plane products equal the plain loop over each layer's
+        mini-jobs, exactly."""
+        hi = 1 << (m * d - 1)
+        A = rng.integers(-hi, hi, size=(24, 5)).astype(np.int16)
+        B = rng.integers(-hi, hi, size=(24, 9))
+        ca = layering._np_decompose(A, m, d)
+        cb = layering._np_decompose(B, m, d)
+        assert ca.dtype == np.int16 and cb.dtype == np.int64
+        want, acc = [], np.zeros((5, 9), np.int64)
+        for l in range(layering.num_layers(m)):
+            for (i, j) in layering.layer_minijobs(m, l):
+                acc = acc + (ca[i].T.astype(np.int64) @ cb[j]
+                             ) * (1 << ((i + j) * d))
+            want.append(acc)
+        got = layering.layered_planes_reference(ca, cb, d=d)
+        np.testing.assert_array_equal(got, np.stack(want))
+        np.testing.assert_array_equal(got[-1], A.T.astype(np.int64) @ B)
+
     def test_jnp_path_matches_reference(self, rng):
         m, d = 2, 7
         hi = 1 << (m * d - 1)

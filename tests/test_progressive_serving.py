@@ -174,14 +174,13 @@ class TestLayeredGradAllreduce:
         1-device mesh XLA elides the wire op, so we check the jaxpr."""
         import jax
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.launch.mesh import make_test_mesh
 
         mesh = make_test_mesh(1, 1)
         m = 3
 
         def fn(planes):
-            return shard_map(
+            return jax.shard_map(
                 lambda p: layered_grads.layered_psum(p, "data"),
                 mesh=mesh, in_specs=P(None, "data"),
                 out_specs=P(None, "data"))(planes)

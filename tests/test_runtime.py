@@ -147,6 +147,22 @@ class TestConfig:
         assert scfg.arrival_rate == cfg.arrival_rate
 
 
+def test_make_jobs_matches_one_int64_draw():
+    """Operands drawn a slab of rows at a time and stored as int16 hold the
+    same values as one int64 draw per operand, in the same order."""
+    cfg = RuntimeConfig(mu=(400.0, 500.0), n1=1, n2=2, m=2, d=7, seed=3)
+    N = 2 * layering.HOST_SLAB_ELEMS // 16 + 2
+    jobs = make_jobs(cfg, 2, K=40, M=4, N=N)
+    rng = np.random.default_rng(cfg.seed)
+    rng.exponential(1.0 / cfg.arrival_rate, size=2)
+    lim = 1 << (cfg.m * cfg.d - 2)
+    for job in jobs:
+        assert job.a.dtype == job.b.dtype == np.int16
+        for got, shape in ((job.a, (40, 4)), (job.b, (40, N))):
+            np.testing.assert_array_equal(
+                got, rng.integers(-lim, lim, size=shape, dtype=np.int64))
+
+
 def _metrics_result(released, L=3):
     """Minimal RuntimeResult with just the fields the metrics under test
     read (released + layer_compute's L)."""
@@ -198,7 +214,7 @@ class TestEndToEnd:
         assert np.nanmax(res.verify_errors) < 1e-9
         # the futures hold the actual products
         jobs = make_jobs(cfg, 6, K=64, M=8, N=8)
-        exact = jobs[0].a.T @ jobs[0].b
+        exact = jobs[0].a.T.astype(np.int64) @ jobs[0].b
         np.testing.assert_allclose(futures[0].resolution(cfg.num_layers - 1),
                                    exact, rtol=1e-9)
 
