@@ -24,13 +24,23 @@ Two budget modes, one release contract:
 The historical ``PlaneBudgetController`` (a serving-local EWMA deadline
 predictor) is gone: ``launch/serve.py`` no longer owns any deadline
 controller.
+
+Each call and each decode step is a :func:`repro.runtime.telemetry.span`
+(``serve.prefill``, ``serve.decode``, ``serve.step``, ``serve.hidden_step``,
+``serve.head``) on the profiler's timeline.  While one records (a tracer
+given to the server, or a profile being taken) the compile stages inside
+it and each step's ``release`` — the time its token is ready on the
+device, stamped by a watcher thread — are recorded too.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import functools
+import queue
+import threading
 from typing import Optional
 
 import jax
@@ -42,7 +52,7 @@ from repro.configs.base import ModelConfig
 from repro.core import progressive
 from repro.launch import compile_cache
 from repro.models import transformer as T
-from repro.runtime import RuntimeConfig, ServingGateway
+from repro.runtime import RuntimeConfig, ServingGateway, telemetry
 
 __all__ = ["ProgressiveServer", "ServeStats", "main"]
 
@@ -117,6 +127,42 @@ class _RuntimeHead:
         self.gateway.stop()
 
 
+def _layered_head(lin, hidden):
+    with jax.named_scope("layered_head"):
+        return progressive.resolution_series(lin,
+                                             hidden.astype(jnp.float32))
+
+
+class _ReleaseWatcher:
+    """Stamps a ``release`` for each decode step when its token is ready
+    on the device.  One thread waits on the steps' tokens in order, so
+    the serving thread never does."""
+
+    def __init__(self, tracer: telemetry.Tracer, job: int):
+        self._steps: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread = threading.Thread(target=self._run,
+                                        args=(tracer, job),
+                                        name="serve-release", daemon=True)
+
+    def __enter__(self) -> "_ReleaseWatcher":
+        self._thread.start()
+        return self
+
+    def put(self, step: int, release: int, tok) -> None:
+        self._steps.put((step, release, tok))
+
+    def _run(self, tracer: telemetry.Tracer, job: int) -> None:
+        while (item := self._steps.get()) is not None:
+            step, release, tok = item
+            tok.block_until_ready()
+            tracer.emit(telemetry.RELEASE, telemetry.clock(), job=job,
+                        round=step, value=float(release))
+
+    def __exit__(self, *exc) -> None:
+        self._steps.put(None)
+        self._thread.join()
+
+
 class ProgressiveServer:
     """Greedy batched decoding with a layered LM head.
 
@@ -124,11 +170,17 @@ class ProgressiveServer:
     up to the final norm, returning ``(hidden (B, D), caches)``;
     ``head_series(hidden)`` (jitted) is the on-chip head's ``m``
     MSB-first resolutions, ``(m, B, V)`` float32.
+
+    ``tracer`` records the serve path's spans without a profiler (see the
+    module's docstring); each :meth:`prefill` begins a new request id
+    (the events' ``job``), which the :meth:`decode` after it carries.
     """
 
     def __init__(self, cfg: ModelConfig, params: dict, *, m: int = 2,
-                 d: int = 7):
+                 d: int = 7, tracer: Optional[telemetry.Tracer] = None):
         self.cfg = cfg
+        self.tracer = tracer
+        self._job = -1
         self.params = params
         w = (params["embed"].T if cfg.tie_embeddings
              else params["lm_head"]).astype(jnp.float32)
@@ -138,7 +190,7 @@ class ProgressiveServer:
         self.d = d
         self._runtime_head: Optional[_RuntimeHead] = None
 
-        def hidden_step(params, token, caches, pos):
+        def hidden(params, token, caches, pos):
             """decode_step but returning final hidden state, not logits."""
             # reuse decode_step minus the head: cheapest correct route is to
             # run it and also recompute hidden; instead we call the internal
@@ -184,12 +236,17 @@ class ProgressiveServer:
                 return x[:, 0, :], (new_caches, enc_kvs)
             return x[:, 0, :], new_caches
 
+        def hidden_step(params, token, caches, pos):
+            with jax.named_scope("hidden_step"):
+                return hidden(params, token, caches, pos)
+
+        # the programs' names (``jit_hidden_step``, ``jit__lambda``) are
+        # what the benchmark's trace reduction finds them by
         self.hidden_step = jax.jit(hidden_step)
         # the head's planes are an argument, not a closure: captured, they
         # would be baked into the program as constants (half a GB at a
         # 4096 x 64000 head)
-        series = jax.jit(lambda lin, h: progressive.resolution_series(
-            lin, h.astype(jnp.float32)))
+        series = jax.jit(lambda lin, h: _layered_head(lin, h))
         self.head_series = functools.partial(series, self.lm_head)
 
     def close(self) -> None:
@@ -206,8 +263,29 @@ class ProgressiveServer:
         self.close()
 
     def prefill(self, tokens, max_len: int, **extras):
-        return T.prefill(self.params, tokens, self.cfg, max_len=max_len,
-                         **extras)
+        self._job += 1
+        with telemetry.span(telemetry.SERVE_PREFILL, "serve.prefill",
+                            self.tracer, job=self._job):
+            return T.prefill(self.params, tokens, self.cfg,
+                             max_len=max_len, **extras)
+
+    def _head(self, hidden, layer_budget, deadline_ms, stats: ServeStats):
+        """The step's logits ``(B, V)`` and the resolution released."""
+        if deadline_ms is not None:
+            # the step is a runtime job: deadline release, best-ready
+            # resolution, and the guaranteed res-0 minimum all come
+            # from the runtime's §IV machinery
+            if self._runtime_head is None:
+                self._runtime_head = _RuntimeHead(
+                    np.asarray(self._head_w), self.m, self.d)
+            logits_np, rel, svc = self._runtime_head.step(
+                np.asarray(hidden, np.float64), deadline_ms / 1e3)
+            stats.head_service_seconds.append(svc)
+            return jnp.asarray(logits_np), rel + 1
+        release = (self.m if layer_budget is None
+                   else max(1, min(layer_budget, self.m)))
+        series = self.head_series(hidden)      # (m, B, V)
+        return series[release - 1], release
 
     def decode(self, tokens, caches, start_pos: int, num_tokens: int, *,
                layer_budget: Optional[int] = None,
@@ -227,33 +305,36 @@ class ProgressiveServer:
         stats = ServeStats(resolutions=(2 * self.m - 1
                                         if deadline_ms is not None
                                         else self.m))
+        job, tr = self._job, self.tracer
+        rec = telemetry.active_tracer(tr)
+        watcher = (contextlib.nullcontext() if rec is None
+                   else _ReleaseWatcher(rec, job))
         tok = tokens
         out = []
-        for i in range(num_tokens):
-            pos = jnp.int32(start_pos + i)
-            hidden, caches = self.hidden_step(self.params, tok, caches, pos)
-            if deadline_ms is not None:
-                # the step is a runtime job: deadline release, best-ready
-                # resolution, and the guaranteed res-0 minimum all come
-                # from the runtime's §IV machinery
-                if self._runtime_head is None:
-                    self._runtime_head = _RuntimeHead(
-                        np.asarray(self._head_w), self.m, self.d)
-                logits_np, rel, svc = self._runtime_head.step(
-                    np.asarray(hidden, np.float64), deadline_ms / 1e3)
-                release = rel + 1
-                stats.head_service_seconds.append(svc)
-                logits = jnp.asarray(logits_np)
-            else:
-                release = (self.m if layer_budget is None
-                           else max(1, min(layer_budget, self.m)))
-                series = self.head_series(hidden)      # (m, B, V)
-                logits = series[release - 1]
-            stats.steps += 1
-            stats.full_resolution += int(release == stats.resolutions)
-            stats.released_at_layer.append(release)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-            out.append(tok)
+        with telemetry.span(telemetry.SERVE_DECODE, "serve.decode", tr,
+                            job=job), watcher:
+            for i in range(num_tokens):
+                with telemetry.span(telemetry.STEP, "serve.step", tr,
+                                    job=job, round=i):
+                    with telemetry.span(telemetry.HIDDEN_STEP,
+                                        "serve.hidden_step", tr, job=job,
+                                        round=i):
+                        hidden, caches = self.hidden_step(
+                            self.params, tok, caches,
+                            jnp.int32(start_pos + i))
+                    with telemetry.span(telemetry.HEAD, "serve.head", tr,
+                                        job=job, round=i):
+                        logits, release = self._head(hidden, layer_budget,
+                                                     deadline_ms, stats)
+                    stats.steps += 1
+                    stats.full_resolution += int(
+                        release == stats.resolutions)
+                    stats.released_at_layer.append(release)
+                    tok = jnp.argmax(logits, axis=-1).astype(
+                        jnp.int32)[:, None]
+                    if rec is not None:
+                        watcher.put(i, release, tok)
+                out.append(tok)
         return jnp.concatenate(out, axis=1), stats
 
 

@@ -35,6 +35,13 @@ Design constraints, in order:
 
 Events are plain ``NamedTuple`` rows (picklable across process/socket
 boundaries); exporters live in :mod:`repro.runtime.trace_export`.
+
+The serve path (:class:`repro.launch.serve.ProgressiveServer`) records
+through :func:`span`, which also puts each span on the profiler's
+timeline as a ``jax.profiler.TraceAnnotation``.  It records into the
+tracer it is given or, while a profile is being taken and none is given,
+into the process's :func:`profile_tracer`; otherwise it records nothing,
+so rule 1 holds there too.
 """
 
 from __future__ import annotations
@@ -44,12 +51,17 @@ import threading
 import time
 from typing import Iterable, List, NamedTuple, Optional, Tuple
 
+import jax
+
 __all__ = [
     "TraceEvent", "Tracer", "EVENT_KINDS", "SPAN_KINDS", "INSTANT_KINDS",
     "PREP", "ENCODE", "DISPATCH", "ROUND", "DECODE", "RESOLUTION", "JOB",
     "RETUNE", "TASK", "RESULT", "FUSED", "STALE", "HEARTBEAT", "RECONNECT",
     "DEAD", "QUARANTINE", "READMIT", "REDISPATCH", "REQUEST", "ADMIT",
-    "RELEASE", "ARENA", "serve_metrics", "worker_metrics_text",
+    "RELEASE", "ARENA", "SERVE_PREFILL", "SERVE_DECODE", "STEP",
+    "HIDDEN_STEP", "HEAD", "COMPILE", "COMPILE_EVENTS", "span",
+    "active_tracer", "profile_tracer", "serve_metrics",
+    "worker_metrics_text",
 ]
 
 clock = time.monotonic
@@ -95,9 +107,25 @@ REQUEST = "request"        # span: submit -> client release; label =
 ADMIT = "admit"            # instant: admission verdict; value = admitted
 #                            resolution (-1 = rejected), label = decision
 RELEASE = "release"        # instant: client release (deadline fire or
-#                            early completion); value = resolution
+#                            early completion), or a decode step's token
+#                            ready on the device (serve path, round =
+#                            step); value = resolution
+# Serve path (repro.launch.serve.ProgressiveServer; job = request id):
+SERVE_PREFILL = "serve_prefill"  # span: host time of one prefill call
+SERVE_DECODE = "serve_decode"    # span: host time of one decode call
+STEP = "step"              # span: host dispatch of one decode step;
+#                            round = step.  Serve spans' value = the
+#                            thread's CPU seconds inside the span
+HIDDEN_STEP = "hidden_step"  # span: dispatch of the step's model program
+HEAD = "head"              # span: dispatch of the step's LM head (the
+#                            runtime job's whole call in deadline mode)
+COMPILE = "compile"        # span: one JAX tracing, lowering, backend-
+#                            compile or compile-cache-read stage inside
+#                            a recorded span; label = the stage's event
 
-SPAN_KINDS = frozenset({PREP, ENCODE, ROUND, DECODE, JOB, TASK, REQUEST})
+SPAN_KINDS = frozenset({PREP, ENCODE, ROUND, DECODE, JOB, TASK, REQUEST,
+                        SERVE_PREFILL, SERVE_DECODE, STEP, HIDDEN_STEP,
+                        HEAD, COMPILE})
 INSTANT_KINDS = frozenset({DISPATCH, RESOLUTION, RETUNE, RESULT, FUSED,
                            STALE, HEARTBEAT, RECONNECT, DEAD, QUARANTINE,
                            READMIT, REDISPATCH, ADMIT, RELEASE, ARENA})
@@ -229,6 +257,131 @@ class Tracer:
         per-thread capacity)."""
         with self._lock:
             return sum(r.dropped for r in self._rings)
+
+
+# -- spans on the profiler's clock -------------------------------------------
+
+#: the ``jax.monitoring`` duration events a :data:`COMPILE` span records.
+#: They nest: the cache read lies inside the backend compile, and a
+#: lowering can trace an inner function.
+COMPILE_EVENTS = frozenset({
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+})
+#: the annotation of a compile stage on the profiler's timeline
+COMPILE_ANNOTATION = "serve.compile"
+
+_TraceAnnotation = jax.profiler.TraceAnnotation
+_PROFILE_TRACER = Tracer()
+_listening = False
+_listen_lock = threading.Lock()
+#: per thread: ``spans``, the recording spans open (innermost last), and
+#: ``stages``, the compile stages annotated and not yet ended
+_local = threading.local()
+
+
+def profile_tracer() -> Tracer:
+    """The process's tracer: what :func:`span` records while a profile
+    is taken and no tracer is given."""
+    return _PROFILE_TRACER
+
+
+def active_tracer(tracer: Optional[Tracer] = None) -> Optional[Tracer]:
+    """Where a span records now: ``tracer`` if given, else the process
+    tracer while a profile is taken, else ``None`` (nothing recorded)."""
+    if tracer is not None:
+        return tracer
+    return profile_tracer() if _TraceAnnotation.is_enabled() else None
+
+
+def _thread_list(name: str) -> list:
+    lst = getattr(_local, name, None)
+    if lst is None:
+        lst = []
+        setattr(_local, name, lst)
+    return lst
+
+
+def _on_stage_start(event: str, value, **kw) -> None:
+    # JAX records a scalar under the stage's own name as the stage begins
+    del value, kw
+    if event in COMPILE_EVENTS and getattr(_local, "spans", None):
+        ann = _TraceAnnotation(COMPILE_ANNOTATION)
+        ann.__enter__()
+        _thread_list("stages").append((event, ann))
+
+
+def _on_stage_end(event: str, secs: float, **kw) -> None:
+    del kw
+    if event not in COMPILE_EVENTS:
+        return
+    stages = getattr(_local, "stages", None)
+    if stages and stages[-1][0] == event:
+        stages.pop()[1].__exit__(None, None, None)
+    spans = getattr(_local, "spans", None)
+    if spans:
+        s = spans[-1]
+        s.tracer.emit(COMPILE, clock() - secs, secs,
+                      job=s.ids.get("job", -1),
+                      round=s.ids.get("round", -1), label=event)
+
+
+def _listen_for_compiles() -> None:
+    """Register the compile listeners, once per process."""
+    global _listening
+    with _listen_lock:
+        if not _listening:
+            jax.monitoring.register_scalar_listener(_on_stage_start)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_stage_end)
+            _listening = True
+
+
+class _Span:
+    """A recording span: a profiler annotation and one event on exit,
+    whose ``value`` (unless given) is the thread's CPU seconds inside
+    the span: its own work, without the time it spent blocked."""
+
+    __slots__ = ("kind", "tracer", "ids", "_ann", "_t", "_cpu")
+
+    def __init__(self, kind: str, name: str, tracer: Tracer, ids: dict):
+        self.kind, self.tracer, self.ids = kind, tracer, ids
+        self._ann = _TraceAnnotation(name)
+
+    def __enter__(self) -> "_Span":
+        if not _listening:
+            _listen_for_compiles()
+        _thread_list("spans").append(self)
+        self._ann.__enter__()
+        self._t = clock()
+        self._cpu = time.thread_time()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1, cpu = clock(), time.thread_time() - self._cpu
+        self._ann.__exit__(*exc)
+        _local.spans.pop()
+        self.tracer.emit(self.kind, self._t, t1 - self._t,
+                         **{"value": cpu, **self.ids})
+
+
+def span(kind: str, name: str, tracer: Optional[Tracer] = None, **ids):
+    """A span of ``kind`` named ``name`` on the profiler's timeline.
+
+    Always a ``jax.profiler.TraceAnnotation(name)``.  It also records a
+    :class:`TraceEvent` (``ids`` are its ``job``, ``round``, ``value``,
+    ``label``; ``value`` defaults to the thread's CPU seconds inside the
+    span) into :func:`active_tracer`, and every compile stage run
+    inside it as a :data:`COMPILE` span with the same ``job`` and
+    ``round``, when that is not ``None``; otherwise it records nothing
+    and keeps nothing.
+    """
+    rec = active_tracer(tracer)
+    if rec is None:
+        return _TraceAnnotation(name)
+    return _Span(kind, name, rec, ids)
 
 
 # -- live metrics endpoint ----------------------------------------------------
