@@ -203,7 +203,9 @@ def serve_phase(cfg, *, batch: int, prompt_len: int, gen: int, m: int,
               f"{n_params / 1e9:.3f} B params, batch {batch}, prompt "
               f"{prompt_len}, {gen} tokens; full-resolution logits within "
               f"the quantisation bound (max err {err.max():.3e}, "
-              f"max err/bound {(err / bound).max():.3e}); init "
+              f"max err/bound {(err / bound).max():.3e}); serving copy "
+              f"in {cfg.compute_dtype} {server.compute_copy_bytes} "
+              f"bytes; init "
               f"{init_s:.2f} s, prefill {prefill_s:.2f} s, first "
               f"hidden_step {step_first_s:.2f} s, decode {decode_s:.2f} s "
               f"(informational)", flush=True)

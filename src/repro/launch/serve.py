@@ -127,6 +127,48 @@ class _RuntimeHead:
         self.gateway.stop()
 
 
+#: stacked matrices a model reads in float32, never through a cast to
+#: the compute dtype: RG-LRU's gate weights (``models/rglru.py``)
+_READ_IN_FLOAT32 = frozenset({"w_a", "w_x"})
+
+
+def _served_in_compute_dtype(path, leaf) -> bool:
+    """Whether the model reads ``leaf`` only as ``leaf.astype(cdtype)``.
+
+    That holds for the float32 embedding, untied head and matrices
+    stacked on a layer axis (rank 3 and up), bar
+    :data:`_READ_IN_FLOAT32`.  Rank-2 leaves stay float32: the MLP
+    biases share that rank with stacked norm gains and recurrence
+    constants, which the models read in float32.
+    """
+    name = getattr(path[-1], "key", None)
+    return leaf.dtype == jnp.float32 and (
+        name in ("embed", "lm_head")
+        or (leaf.ndim >= 3 and name not in _READ_IN_FLOAT32))
+
+
+def _compute_copy(params: dict, cdtype) -> tuple[dict, int]:
+    """``(params with each leaf the model only casts to cdtype cast once,
+    bytes of the cast leaves)``.
+
+    The values are the ones the model's per-step ``astype`` makes, so
+    serving from the copy is bit-exact; every other leaf is the caller's
+    own array.  With float32 compute the caller's tree comes back.
+    """
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    pick = [i for i, (path, leaf) in enumerate(flat)
+            if _served_in_compute_dtype(path, leaf)]
+    if jnp.dtype(cdtype) == jnp.float32 or not pick:
+        return params, 0
+    leaves = [leaf for _, leaf in flat]
+    cast = jax.jit(lambda xs: [x.astype(cdtype) for x in xs])(
+        [leaves[i] for i in pick])
+    for i, x in zip(pick, cast):
+        leaves[i] = x
+    return (jax.tree_util.tree_unflatten(treedef, leaves),
+            sum(x.nbytes for x in cast))
+
+
 def _layered_head(lin, hidden):
     with jax.named_scope("layered_head"):
         return progressive.resolution_series(lin,
@@ -174,6 +216,14 @@ class ProgressiveServer:
     ``tracer`` records the serve path's spans without a profiler (see the
     module's docstring); each :meth:`prefill` begins a new request id
     (the events' ``job``), which the :meth:`decode` after it carries.
+
+    ``params`` are the master weights, which the server neither changes
+    nor frees.  It serves from ``self.params``: a copy in which every
+    leaf the model reads only through a cast to ``cfg.cdtype()`` is cast
+    once, here, instead of in every prefill and decode step; the head's
+    planes and the deadline head are made from the masters.
+    ``compute_copy_bytes`` counts the cast leaves' bytes (0 with float32
+    compute, where ``self.params`` is ``params``).
     """
 
     def __init__(self, cfg: ModelConfig, params: dict, *, m: int = 2,
@@ -181,11 +231,12 @@ class ProgressiveServer:
         self.cfg = cfg
         self.tracer = tracer
         self._job = -1
-        self.params = params
         w = (params["embed"].T if cfg.tie_embeddings
              else params["lm_head"]).astype(jnp.float32)
         self.lm_head = progressive.make_layered_linear(w, m=m, d=d)
         self._head_w = w
+        self.params, self.compute_copy_bytes = _compute_copy(params,
+                                                             cfg.cdtype())
         self.m = m
         self.d = d
         self._runtime_head: Optional[_RuntimeHead] = None
