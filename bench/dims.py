@@ -1,8 +1,9 @@
-"""A model configuration file, read into the sizes the benchmark uses.
+"""A dense model's configuration file, read into the sizes the benchmark
+uses (``bench/families/dense.py``).
 
 The files under ``bench/configs/`` keep the public ``config.json`` keys
 of the model they name; this module reads them and nothing else, so the
-benchmark's work counts, weights and reference all follow the file.
+dense family's work counts, weights and reference all follow the file.
 """
 
 from __future__ import annotations

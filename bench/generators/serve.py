@@ -29,45 +29,35 @@ first token, and its logits at the last prompt position.
 
 Every window holds at least ``check_requests`` requests, so that a run
 compares as many as its traffic file says, however short its window.
+
+Nothing here knows the model's kind: the configuration's family
+(``bench/families/<config reference>.py``) gives the program's
+``ModelConfig`` and the weights, and its reference the check's logits.
 """
 
 from __future__ import annotations
 
 import gc
-import importlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
-from bench.dims import Dims
 from bench.window import Window, clock, span
 
-__all__ = ["run", "model_config", "compare", "Server"]
-
-
-def model_config(d: Dims):
-    """The program's configuration of the model in a configuration file."""
-    from repro.configs.base import AttentionConfig, ModelConfig
-    return ModelConfig(
-        name=d.name, family="dense", num_layers=d.n_layers,
-        d_model=d.d_model, d_ff=d.d_ff, vocab_size=d.vocab,
-        attention=AttentionConfig(num_heads=d.n_heads, num_kv_heads=d.n_kv,
-                                  head_dim=d.head_dim,
-                                  rope_theta=d.rope_theta),
-        activation=d.act, norm=d.norm, tie_embeddings=d.tie)
+__all__ = ["run", "compare", "Server"]
 
 
 class Server:
     """The timed path: one request is a prefill then ``gen`` decode steps."""
 
-    def __init__(self, d: Dims, traffic: dict, key_data):
+    def __init__(self, family, d, traffic: dict, key_data):
         from repro.launch.serve import ProgressiveServer
         self.B, self.P, self.G = (traffic["batch"], traffic["prompt"],
                                   traffic["gen"])
-        self.params = W.make_params(key_data, d)
-        self.server = ProgressiveServer(model_config(d), self.params,
+        self.params = family.make_params(key_data, d)
+        self.server = ProgressiveServer(family.model_config(d), self.params,
                                         m=traffic["head_m"],
                                         d=traffic["head_d"])
         jax.block_until_ready((self.params, self.server.lm_head))
@@ -99,16 +89,16 @@ def _prompt(rng, traffic, vocab):
                         dtype=np.int64)
 
 
-def compare(d: Dims, config: dict, key_data, prompt: np.ndarray,
-            served: np.ndarray, first_logits: np.ndarray, *,
+def compare(ref, d, key_data, prompt: np.ndarray, served: np.ndarray,
+            first_logits: np.ndarray, *,
             control: bool = False) -> tuple[float, float]:
-    """``(logit_gap, logit_err)`` of one finished request.
+    """``(logit_gap, logit_err)`` of one finished request, by the plain
+    reference module ``ref`` (``harness.reference``) at sizes ``d``.
 
     ``served`` (B, G+1) are the tokens served after ``prompt`` (B, P), and
     ``first_logits`` (B, V) the prefill's logits at the last prompt
     position.  With ``control`` the float8 reference stands in for both.
     """
-    ref = importlib.import_module(f"bench.reference.{config['reference']}")
     P, n = prompt.shape[1], served.shape[1]
     seq = jnp.asarray(np.concatenate([prompt, served[:, :-1]], 1), jnp.int32)
 
@@ -134,7 +124,7 @@ def run(rec, devices, *, t_start: float, trace_dir=None) -> None:
     from bench import harness
     cell, tr, d = rec.cell, rec.cell.traffic, rec.cell.dims
     key_data = W.seed_key(rec.seed)
-    srv = Server(d, tr, key_data)
+    srv = Server(harness.family(cell), d, tr, key_data)
     # warm every shape the window uses with one request of its own prompt
     srv.request(_prompt(np.random.default_rng([rec.seed, 1]), tr, d.vocab))
 
@@ -180,7 +170,8 @@ def run(rec, devices, *, t_start: float, trace_dir=None) -> None:
     del srv, done
     gc.collect()
     t0 = clock()
-    readings = [compare(d, cell.config, key_data, *c, control=rec.control)
+    ref = harness.reference(cell)
+    readings = [compare(ref, d, key_data, *c, control=rec.control)
                 for c in checked]
     print(f"[serve] {'float8 control' if rec.control else 'reference'} "
           f"over {len(checked)} request(s), "

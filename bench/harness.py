@@ -4,6 +4,10 @@
 harness finds what belongs to each in a file of its own:
 
 * ``configs[].file``             the configuration (public config keys);
+  its ``reference`` key names its model family
+  (``bench/families/<reference>.py``, see ``bench/families/__init__.py``),
+  which reads the file into the cell's sizes, and the plain reference
+  (``bench/reference/<reference>.py``) the check compares against;
 * ``bench/traffic/<traffic>.json``  the traffic mix; its ``generator`` names
   the general generator in ``bench/generators/<generator>.py`` that runs it;
 * ``bench/cells/<workload>.json``   the limits that decide ``correct``;
@@ -18,6 +22,7 @@ is an error that names it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -25,10 +30,9 @@ import pathlib
 import sys
 from typing import Any
 
-from bench.dims import Dims
-
 __all__ = ["Cell", "Run", "load_benchmark", "resolve", "device_info",
-           "check_devices", "reader", "report"]
+           "check_devices", "generator", "family", "reference", "reader",
+           "report"]
 
 #: the benchmark's directory in a checkout, beside BENCHMARK.json
 DIR = "bench"
@@ -44,7 +48,7 @@ class Cell:
     dir: pathlib.Path      # the checkout's bench directory
     chips: int
     config: dict
-    dims: Dims
+    dims: Any              # the family's sizes: family(cell).dims(config)
     traffic: dict
     limits: dict
     end_to_end: list       # metric entries of BENCHMARK.json this cell reports
@@ -104,6 +108,11 @@ def resolve(root: pathlib.Path, bench: dict, workload: str) -> Cell:
         raise BenchError(f"{workload}: no configuration {w['config']!r}")
     config = json.loads(_need(root / configs[w["config"]]["file"],
                               f"configuration {w['config']}").read_text())
+    fam = _load_module(_need(_family_path(here, config),
+                             f"family of configuration {w['config']}"),
+                       f"bench_family_{config['reference']}")
+    _need(_reference_path(here, config),
+          f"reference of configuration {w['config']}")
     traffic = json.loads(_need(here / "traffic" / f"{w['traffic']}.json",
                                f"traffic {w['traffic']}").read_text())
     _need(here / "generators" / f"{traffic['generator']}.py",
@@ -114,25 +123,48 @@ def resolve(root: pathlib.Path, bench: dict, workload: str) -> Cell:
     for m in per_layer:
         _need(here / "metrics" / f"{m['name']}.py", f"metric {m['name']}")
     return Cell(name=workload, dir=here, chips=int(w["chips"]),
-                config=config,
-                dims=Dims.from_config(config), traffic=traffic,
+                config=config, dims=fam.dims(config), traffic=traffic,
                 limits=limits["limits"],
                 end_to_end=[m for m in bench["end_to_end"]
                             if _applies(m, workload)],
                 per_layer=per_layer)
 
 
+@functools.lru_cache(maxsize=None)
 def _load_module(path: pathlib.Path, name: str):
+    """The module in the file ``path``, run once a process."""
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
+def _family_path(here: pathlib.Path, config: dict) -> pathlib.Path:
+    return here / "families" / f"{config['reference']}.py"
+
+
+def _reference_path(here: pathlib.Path, config: dict) -> pathlib.Path:
+    return here / "reference" / f"{config['reference']}.py"
+
+
 def generator(cell: Cell):
     d = cell.traffic["generator"]
     return _load_module(cell.dir / "generators" / f"{d}.py",
                         f"bench_generator_{d}")
+
+
+def family(cell: Cell):
+    """The module of the cell's model family: ``dims``, ``model_config``,
+    ``make_params`` and ``request_flops``."""
+    return _load_module(_family_path(cell.dir, cell.config),
+                        f"bench_family_{cell.config['reference']}")
+
+
+def reference(cell: Cell):
+    """The module of the cell's plain reference: ``hidden`` and
+    ``logits``."""
+    return _load_module(_reference_path(cell.dir, cell.config),
+                        f"bench_reference_{cell.config['reference']}")
 
 
 def reader(cell: Cell, metric: str):

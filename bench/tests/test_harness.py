@@ -24,6 +24,8 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 def test_every_cell_resolves(cell):
     c = harness.resolve(REPO, BENCH, cell)
     assert c.limits and c.traffic["generator"] == "serve"
+    d = harness.family(c).dims(c.config)
+    assert d == c.dims and d.d_model > 0 and d.vocab > 0
     names = {m["name"] for m in c.end_to_end}
     assert "setup_s" in names and len(names) >= 2
     assert c.per_layer
@@ -73,7 +75,9 @@ def test_missing_file_is_named(tmp_path):
         harness.resolve(root, harness.load_benchmark(root),
                         "yi6b-chat-decode")
     for gone in (root / "bench" / "metrics" / "prefill_ms.py",
-                 root / "bench" / "cells" / "starcoder2-code-prefill.json"):
+                 root / "bench" / "cells" / "starcoder2-code-prefill.json",
+                 root / "bench" / "reference" / "dense.py",
+                 root / "bench" / "families" / "dense.py"):
         gone.unlink()
         with pytest.raises(harness.BenchError, match=re.escape(str(gone))):
             harness.resolve(root, harness.load_benchmark(root),

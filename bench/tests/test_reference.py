@@ -15,7 +15,7 @@ import pytest
 
 from bench import weights as W
 from bench.dims import Dims
-from bench.generators.serve import model_config
+from bench.families.dense import model_config
 from bench.reference import dense
 
 SMOKE = {

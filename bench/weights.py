@@ -1,4 +1,5 @@
-"""Random weights from the seed, in the layout the served model takes.
+"""Random weights of a dense model from the seed (``bench/families/dense.py``),
+in the layout the served model takes.
 
 The benchmark, not the program, makes the weights: ``make_params`` builds
 the whole tree on the device in one jitted call, in float32 (the
