@@ -34,6 +34,24 @@ class AttentionConfig:
     causal: bool = True
     qk_norm: bool = False
     attn_logit_softcap: Optional[float] = None
+    # latent attention (DeepSeek-V2 MLA; kv_lora_rank 0 = none): keys and
+    # values come from a cached (kv_lora_rank + qk_rope_head_dim) latent;
+    # head_dim is then qk_nope_head_dim + qk_rope_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling (rope_factor 1 = none), with the paper's and
+    # DeepSeek-V2's beta_fast 32 and beta_slow 1 (layers.yarn_inv_freq).
+    # The scores take mscale_all_dim; the rotary's own mscale is taken
+    # equal to it, so cos and sin are not rescaled (DeepSeek-V2's setting)
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    rope_mscale_all_dim: float = 0.0
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def q_dim(self) -> int:
@@ -61,6 +79,12 @@ class MoEConfig:
     interleave_step: int = 1              # every n-th layer is MoE (1 = all)
     dispatch_group: int = 4096            # tokens per dispatch group (GShard G)
     # 1 -> all layers MoE; 2 -> layers 1,3,5,... MoE (llama4-style)
+    first_dense_layers: int = 0           # leading dense layers (DeepSeek)
+    # (first, count): the contiguous block of experts this chip holds.
+    # Set, the layer routes over all num_experts and computes its own
+    # share, dropping no token (models/moe.held_moe_block); None, the
+    # GShard capacity path over all experts (models/moe.moe_block)
+    held_experts: Optional[tuple[int, int]] = None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -31,6 +31,7 @@ ARCH_IDS: dict[str, str] = {
     "starcoder2-7b": "starcoder2_7b",
     "whisper-tiny": "whisper_tiny",
     "recurrentgemma-9b": "recurrentgemma_9b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

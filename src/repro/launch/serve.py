@@ -30,7 +30,8 @@ Each call and each decode step is a :func:`repro.runtime.telemetry.span`
 ``serve.head``) on the profiler's timeline.  While one records (a tracer
 given to the server, or a profile being taken) the compile stages inside
 it and each step's ``release`` — the time its token is ready on the
-device, stamped by a watcher thread — are recorded too.
+device, stamped by a watcher thread — are recorded too, and, for a model
+with held-share expert layers, the step's ``expert_pairs``.
 """
 
 from __future__ import annotations
@@ -52,6 +53,7 @@ from repro.configs.base import ModelConfig
 from repro.core import progressive
 from repro.launch import compile_cache
 from repro.models import transformer as T
+from repro.models.layers import apply_norm
 from repro.runtime import RuntimeConfig, ServingGateway, telemetry
 
 __all__ = ["ProgressiveServer", "ServeStats", "main"]
@@ -128,8 +130,9 @@ class _RuntimeHead:
 
 
 #: stacked matrices a model reads in float32, never through a cast to
-#: the compute dtype: RG-LRU's gate weights (``models/rglru.py``)
-_READ_IN_FLOAT32 = frozenset({"w_a", "w_x"})
+#: the compute dtype: RG-LRU's gate weights (``models/rglru.py``) and the
+#: expert router, which both MoE paths read in float32 (``models/moe.py``)
+_READ_IN_FLOAT32 = frozenset({"w_a", "w_x", "router"})
 
 
 def _served_in_compute_dtype(path, leaf) -> bool:
@@ -177,8 +180,10 @@ def _layered_head(lin, hidden):
 
 class _ReleaseWatcher:
     """Stamps a ``release`` for each decode step when its token is ready
-    on the device.  One thread waits on the steps' tokens in order, so
-    the serving thread never does."""
+    on the device, and then copies the step's expert group sizes, if it
+    has any, into one ``expert_pairs`` event per MoE layer and held
+    expert.  One thread waits on the steps' tokens in order, so the
+    serving thread never does."""
 
     def __init__(self, tracer: telemetry.Tracer, job: int):
         self._steps: queue.SimpleQueue = queue.SimpleQueue()
@@ -190,15 +195,23 @@ class _ReleaseWatcher:
         self._thread.start()
         return self
 
-    def put(self, step: int, release: int, tok) -> None:
-        self._steps.put((step, release, tok))
+    def put(self, step: int, release: int, tok, pairs=None) -> None:
+        self._steps.put((step, release, tok, pairs))
 
     def _run(self, tracer: telemetry.Tracer, job: int) -> None:
         while (item := self._steps.get()) is not None:
-            step, release, tok = item
+            step, release, tok, pairs = item
             tok.block_until_ready()
-            tracer.emit(telemetry.RELEASE, telemetry.clock(), job=job,
-                        round=step, value=float(release))
+            t = telemetry.clock()
+            tracer.emit(telemetry.RELEASE, t, job=job, round=step,
+                        value=float(release))
+            if pairs is None:
+                continue
+            for layer, row in enumerate(np.asarray(pairs).tolist()):
+                for expert, n in enumerate(row):
+                    tracer.emit(telemetry.EXPERT_PAIRS, t, job=job,
+                                round=step, task=layer, worker=expert,
+                                value=float(n))
 
     def __exit__(self, *exc) -> None:
         self._steps.put(None)
@@ -209,7 +222,9 @@ class ProgressiveServer:
     """Greedy batched decoding with a layered LM head.
 
     ``hidden_step(params, token, caches, pos)`` (jitted) is one decode step
-    up to the final norm, returning ``(hidden (B, D), caches)``;
+    up to the final norm, returning ``(hidden (B, D), caches)``, and for a
+    model with held-share expert layers also the step's group sizes,
+    ``(MoE layers, held experts)`` int32 (``transformer.decode_layers``);
     ``head_series(hidden)`` (jitted) is the on-chip head's ``m``
     MSB-first resolutions, ``(m, B, V)`` float32.
 
@@ -242,50 +257,13 @@ class ProgressiveServer:
         self._runtime_head: Optional[_RuntimeHead] = None
 
         def hidden(params, token, caches, pos):
-            """decode_step but returning final hidden state, not logits."""
-            # reuse decode_step minus the head: cheapest correct route is to
-            # run it and also recompute hidden; instead we call the internal
-            # machinery directly.
-            x = T._embed_inputs(params, token, cfg)
-            new_caches = []
-            if cfg.is_encdec:
-                caches, enc_kvs = caches
-            gi = 0
-            from repro.models.transformer import (_layer_decode,
-                                                  block_groups)
-            for g, (unit, reps) in enumerate(block_groups(cfg)):
-                unit_params = params["groups"][g]
-                unit_cache = caches[g]
-                if cfg.is_encdec:
-                    ek, ev = enc_kvs[gi]
-                    gi += 1
-
-                    def body(h, xs):
-                        pl_, cl, ekl, evl = xs
-                        h, c = _layer_decode("cross", pl_, h, cl, cfg, pos,
-                                             enc_kv=(ekl, evl))
-                        return h, c
-
-                    x, nc = jax.lax.scan(body, x, (unit_params[0],
-                                                   unit_cache[0], ek, ev))
-                    new_caches.append([nc])
-                    continue
-
-                def body(h, xs):
-                    pl_, cl = xs
-                    ncs = []
-                    for kind, pk, ck in zip(unit, pl_, cl):
-                        h, nc_ = _layer_decode(kind, pk, h, ck, cfg, pos)
-                        ncs.append(nc_)
-                    return h, ncs
-
-                x, nc = jax.lax.scan(body, x, (unit_params, unit_cache))
-                new_caches.append(nc)
-            from repro.models.layers import apply_norm
-            x = apply_norm(cfg.norm, x, params["final_norm"])
-            if cfg.is_encdec:
-                return x[:, 0, :], (new_caches, enc_kvs)
-            return x[:, 0, :], new_caches
+            """decode_step up to the final norm, not the logits."""
+            x, caches, pairs = T.decode_layers(params, token, caches, pos,
+                                               cfg)
+            x = apply_norm(cfg.norm, x, params["final_norm"])[:, 0, :]
+            # a model without held-share layers keeps the two outputs its
+            # callers (and stand-ins for the step) unpack
+            return (x, caches) if pairs is None else (x, caches, pairs)
 
         def hidden_step(params, token, caches, pos):
             with jax.named_scope("hidden_step"):
@@ -370,7 +348,7 @@ class ProgressiveServer:
                     with telemetry.span(telemetry.HIDDEN_STEP,
                                         "serve.hidden_step", tr, job=job,
                                         round=i):
-                        hidden, caches = self.hidden_step(
+                        hidden, caches, *pairs = self.hidden_step(
                             self.params, tok, caches,
                             jnp.int32(start_pos + i))
                     with telemetry.span(telemetry.HEAD, "serve.head", tr,
@@ -384,7 +362,7 @@ class ProgressiveServer:
                     tok = jnp.argmax(logits, axis=-1).astype(
                         jnp.int32)[:, None]
                     if rec is not None:
-                        watcher.put(i, release, tok)
+                        watcher.put(i, release, tok, *pairs)
                 out.append(tok)
         return jnp.concatenate(out, axis=1), stats
 

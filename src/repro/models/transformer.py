@@ -18,6 +18,13 @@ Layer kinds:
   local_attn  sliding-window GQA + MLP (recurrentgemma's attention layers)
   cross       encoder-decoder layer: causal self-attn + cross-attn + MLP
 
+With ``attention.kv_lora_rank`` set, the attention of dense and moe
+layers is latent attention (DeepSeek-V2's MLA): the cache holds one
+``(B, max_len, kv_lora_rank + qk_rope_head_dim)`` latent per layer in
+place of per-head K/V, prefill expands it and decode absorbs the
+up-projection (``models/layers.py``).  ``moe.first_dense_layers`` puts a
+group of dense layers before the MoE group.
+
 Caches: every kind owns a cache pytree stacked like its params; decode
 scans over (params, cache) pairs and emits updated caches.
 """
@@ -40,7 +47,8 @@ from repro.models import ssm as ssm_lib
 
 __all__ = [
     "block_groups", "init_params", "forward_train", "prefill",
-    "decode_step", "init_cache", "count_params", "active_params",
+    "decode_layers", "decode_step", "init_cache", "count_params",
+    "active_params",
 ]
 
 # Static KV-cache quantization scale (int8 mode).  Keys/values are
@@ -85,6 +93,10 @@ def block_groups(cfg: ModelConfig) -> list[tuple[tuple[str, ...], int]]:
         if rem:
             groups.append((unit[:rem], 1))
         return groups
+    if cfg.family == "moe" and cfg.moe.first_dense_layers:
+        n = cfg.moe.first_dense_layers
+        assert cfg.moe.interleave_step == 1 and 0 < n < Lnum, (n, Lnum)
+        return [(("dense",), n), (("moe",), Lnum - n)]
     if cfg.family == "moe" and cfg.moe.interleave_step > 1:
         step = cfg.moe.interleave_step
         assert Lnum % step == 0, (Lnum, step)
@@ -106,6 +118,19 @@ def _init_attn(key, cfg: ModelConfig, reps: tuple[int, ...]) -> dict:
     ks = jax.random.split(key, 4)
     dt = cfg.pdtype()
     D = cfg.d_model
+    if a.latent:
+        H, r = a.num_heads, a.kv_lora_rank
+        nope, rope, dv = a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim
+        return {
+            "wq": L.init_linear(ks[0], D, H * (nope + rope), dt, reps
+                                ).reshape(reps + (D, H, nope + rope)),
+            "wkv_a": L.init_linear(ks[1], D, r + rope, dt, reps),
+            "kv_norm": {"scale": jnp.zeros(reps + (r,), dt)},
+            "wkv_b": L.init_linear(ks[2], r, H * (nope + dv), dt, reps
+                                   ).reshape(reps + (r, H, nope + dv)),
+            "wo": L.init_linear(ks[3], H * dv, D, dt, reps
+                                ).reshape(reps + (H, dv, D)),
+        }
     return {
         "wq": L.init_linear(ks[0], D, a.num_heads * a.head_dim, dt, reps
                             ).reshape(reps + (D, a.num_heads, a.head_dim)),
@@ -239,8 +264,42 @@ def _attn_apply(p: dict, x: jax.Array, cfg: ModelConfig, pos_q, pos_k,
     return out, (k, v)
 
 
+def _latent_project(p: dict, x: jax.Array, cfg: ModelConfig, positions):
+    """Latent attention's projections of x (B, S, D) at ``positions`` (B,
+    S): the query's no-rope and roped parts (B, S, H, nope / rope) and
+    the token's cache row (B, S, rank + rope), the normed latent beside
+    the roped key part all heads share."""
+    a = cfg.attention
+    r, nope = a.kv_lora_rank, a.qk_nope_head_dim
+    inv_freq = L.yarn_inv_freq(a, a.qk_rope_head_dim)
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+    q_pe = L.rope(q[..., nope:], positions, a.rope_theta, inv_freq)
+    kv = x @ p["wkv_a"].astype(x.dtype)
+    c = L.rms_norm(kv[..., :r], p["kv_norm"]["scale"])
+    k_pe = L.rope(kv[..., None, r:], positions, a.rope_theta, inv_freq)
+    return q[..., :nope], q_pe, jnp.concatenate([c, k_pe[..., 0, :]], -1)
+
+
+def _latent_attn_fwd(p: dict, x: jax.Array, cfg: ModelConfig, positions,
+                     q_chunk=2048):
+    """Latent attention over a whole sequence.  Returns (out, ckv)."""
+    with jax.named_scope("latent_attention"):
+        q_nope, q_pe, ckv = _latent_project(p, x, cfg, positions)
+        out = L.latent_attention(q_nope, q_pe, ckv,
+                                 p["wkv_b"].astype(x.dtype), positions,
+                                 positions, cfg.attention, q_chunk=q_chunk)
+        out = jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(x.dtype))
+    return constrain(out, "batch", None, None), ckv
+
+
 def _ffn_apply(p: dict, x: jax.Array, cfg: ModelConfig, kind: str):
-    if kind == "moe":
+    """The layer's MLP or expert layer.  Returns (out, pairs): a
+    held-share expert layer's group sizes (held experts,) int32, else
+    None."""
+    pairs = None
+    if kind == "moe" and cfg.moe.held_experts:
+        out, pairs = moe_lib.held_moe_block(p, x, cfg.moe)
+    elif kind == "moe":
         out = moe_lib.moe_block(p, x, cfg.moe)
     elif cfg.activation == "gelu":
         h = jax.nn.gelu(x @ p["w_fc"].astype(x.dtype)
@@ -252,7 +311,7 @@ def _ffn_apply(p: dict, x: jax.Array, cfg: ModelConfig, kind: str):
              * (x @ p["w_up"].astype(x.dtype)))
         h = constrain(h, "batch", None, "tp")
         out = h @ p["w_down"].astype(x.dtype)
-    return constrain(out, "batch", None, None)
+    return constrain(out, "batch", None, None), pairs
 
 
 def _layer_fwd(kind: str, p: dict, x: jax.Array, cfg: ModelConfig,
@@ -260,7 +319,13 @@ def _layer_fwd(kind: str, p: dict, x: jax.Array, cfg: ModelConfig,
     """Full-sequence layer forward.  Returns (x, cache_entry)."""
     norm = lambda n, h: L.apply_norm(cfg.norm, h, n)
     cache: dict[str, Any] = {}
-    if kind in ("dense", "moe", "local_attn", "cross"):
+    if kind in ("dense", "moe") and cfg.attention.latent:
+        h, ckv = _latent_attn_fwd(p["attn"], norm(p["ln1"], x), cfg,
+                                  positions, q_chunk=q_chunk)
+        x = x + h
+        x = x + _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, kind)[0]
+        cache = {"ckv": ckv}
+    elif kind in ("dense", "moe", "local_attn", "cross"):
         window = cfg.rglru.window if (kind == "local_attn" and cfg.rglru) \
             else cfg.attention.window
         h, (k, v) = _attn_apply(p["attn"], norm(p["ln1"], x), cfg,
@@ -274,7 +339,7 @@ def _layer_fwd(kind: str, p: dict, x: jax.Array, cfg: ModelConfig,
                                positions, enc_pos, k_ext=ek, v_ext=ev,
                                causal=False, q_chunk=q_chunk)
             x = x + h
-        x = x + _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, kind)
+        x = x + _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, kind)[0]
         if kind == "local_attn" and cfg.rglru:
             W = cfg.rglru.window
             cache = {"k": k[:, -W:], "v": v[:, -W:],
@@ -289,16 +354,31 @@ def _layer_fwd(kind: str, p: dict, x: jax.Array, cfg: ModelConfig,
         h, cache = rglru_lib.rglru_block(p["rglru"], norm(p["ln1"], x),
                                          cfg.rglru)
         x = x + h
-        x = x + _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, "dense")
+        x = x + _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, "dense")[0]
     return x, cache
 
 
 def _layer_decode(kind: str, p: dict, x: jax.Array, cache: dict,
                   cfg: ModelConfig, pos: jax.Array, enc_kv=None):
-    """Single-token layer step against a cache.  Returns (x, new_cache)."""
+    """Single-token layer step against a cache.  Returns (x, new_cache,
+    pairs): a held-share expert layer's group sizes, else None."""
     norm = lambda n, h: L.apply_norm(cfg.norm, h, n)
     B = x.shape[0]
     pos_b = jnp.broadcast_to(pos, (B,))
+    if kind in ("dense", "moe") and cfg.attention.latent:
+        a, ap = cfg.attention, p["attn"]
+        with jax.named_scope("latent_attention"):
+            q_nope, q_pe, ckv = _latent_project(ap, norm(p["ln1"], x), cfg,
+                                                pos_b[:, None])
+            ckv_cache = jax.lax.dynamic_update_slice_in_dim(
+                cache["ckv"], _quant_kv(ckv, cfg), pos, 1)
+            out = L.latent_decode_attention(
+                q_nope, q_pe, _dequant_kv(ckv_cache, cfg),
+                ap["wkv_b"].astype(x.dtype), a, cache_len=pos_b + 1)
+            x = x + jnp.einsum("bshv,hvd->bsd", out,
+                               ap["wo"].astype(x.dtype))
+        h, pairs = _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, kind)
+        return x + h, {"ckv": ckv_cache}, pairs
     if kind in ("dense", "moe", "local_attn", "cross"):
         a = cfg.attention
         hin = norm(p["ln1"], x)
@@ -345,19 +425,19 @@ def _layer_decode(kind: str, p: dict, x: jax.Array, cache: dict,
                                pos_b[:, None], enc_pos, k_ext=ek, v_ext=ev,
                                causal=False)
             x = x + h
-        x = x + _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, kind)
-        return x, new_cache
+        h, pairs = _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, kind)
+        return x + h, new_cache, pairs
     if kind == "ssm":
         h, new_cache = ssm_lib.ssm_decode_step(p["ssm"], norm(p["ln1"], x),
                                                cache, cfg.d_model, cfg.ssm)
-        return x + h, new_cache
+        return x + h, new_cache, None
     if kind == "rglru":
         h, new_cache = rglru_lib.rglru_decode_step(p["rglru"],
                                                    norm(p["ln1"], x), cache,
                                                    cfg.rglru)
         x = x + h
-        x = x + _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, "dense")
-        return x, new_cache
+        x = x + _ffn_apply(p["ffn"], norm(p["ln2"], x), cfg, "dense")[0]
+        return x, new_cache, None
     raise ValueError(kind)
 
 
@@ -393,6 +473,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> list:
             elif kind == "rglru":
                 c = rglru_lib.init_rglru_cache(batch, cfg.d_model, cfg.rglru,
                                                dt)
+            elif a.latent:
+                c = {"ckv": jnp.zeros((batch, max_len, a.kv_lora_rank
+                                       + a.qk_rope_head_dim), kv_dt)}
             elif kind == "local_attn":
                 W = cfg.rglru.window if cfg.rglru else a.window
                 c = {"k": jnp.zeros((batch, W, a.num_kv_heads, a.head_dim),
@@ -539,7 +622,11 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int, *,
         unit_caches = []
         for u, kind in enumerate(unit):
             c = caches[g][u]
-            if kind in ("dense", "moe", "cross") and "k" in c:
+            if "ckv" in c:
+                pad = [(0, 0)] * c["ckv"].ndim
+                pad[2] = (0, max_len - S)
+                c = {"ckv": jnp.pad(_quant_kv(c["ckv"], cfg), pad)}
+            elif kind in ("dense", "moe", "cross") and "k" in c:
                 pad = [(0, 0)] * c["k"].ndim
                 pad[2] = (0, max_len - S)
                 c = {"k": jnp.pad(_quant_kv(c["k"], cfg), pad),
@@ -555,18 +642,21 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int, *,
     return logits[:, -1, :], padded
 
 
-def decode_step(params, token: jax.Array, caches, pos: jax.Array,
-                cfg: ModelConfig, *, enc_kvs=None):
-    """One serving step: token (B, 1) at position ``pos`` (scalar int32).
+def decode_layers(params, token: jax.Array, caches, pos: jax.Array,
+                  cfg: ModelConfig, *, enc_kvs=None):
+    """The layers of one serving step: token (B, 1) at position ``pos``
+    (scalar int32).
 
-    Returns (logits (B, V), new caches).  The KV/state caches are donated in
-    the jitted serve_step (see launch/serve.py) so updates are in-place.
+    Returns (x (B, 1, D) before the final norm, new caches, the held-share
+    expert layers' group sizes (MoE layers, held experts) int32 or
+    ``None`` without such layers).  Encoder-decoder caches come and go as
+    ``(caches, enc_kvs)`` unless ``enc_kvs`` is given.
     """
     x = _embed_inputs(params, token, cfg)
     if cfg.is_encdec and enc_kvs is None:
         caches, enc_kvs = caches
 
-    new_caches = []
+    new_caches, pairs = [], []
     gi = 0
     for g, (unit, reps) in enumerate(block_groups(cfg)):
         unit_params = params["groups"][g]
@@ -578,8 +668,8 @@ def decode_step(params, token: jax.Array, caches, pos: jax.Array,
 
             def body(h, xs):
                 pl, cl, ekl, evl = xs
-                h, c = _layer_decode("cross", pl, h, cl, cfg, pos,
-                                     enc_kv=(ekl, evl))
+                h, c, _ = _layer_decode("cross", pl, h, cl, cfg, pos,
+                                        enc_kv=(ekl, evl))
                 return h, c
 
             x, ncache = jax.lax.scan(body, x,
@@ -589,18 +679,33 @@ def decode_step(params, token: jax.Array, caches, pos: jax.Array,
 
         def body(h, xs):
             pl, cl = xs
-            ncs = []
+            ncs, prs = [], []
             for kind, pk, ck in zip(unit, pl, cl):
-                h, nc = _layer_decode(kind, pk, h, ck, cfg, pos)
+                h, nc, pr = _layer_decode(kind, pk, h, ck, cfg, pos)
                 ncs.append(nc)
-            return h, ncs
+                prs.append(pr)
+            return h, (ncs, prs)
 
-        x, ncache = jax.lax.scan(body, x, (unit_params, unit_cache))
+        x, (ncache, prs) = jax.lax.scan(body, x, (unit_params, unit_cache))
         new_caches.append(ncache)
+        pairs.extend(pr for pr in prs if pr is not None)
 
-    logits = _lm_logits(params, x, cfg)
     if cfg.is_encdec:
-        return logits[:, 0, :], (new_caches, enc_kvs)
+        new_caches = (new_caches, enc_kvs)
+    return x, new_caches, (jnp.concatenate(pairs) if pairs else None)
+
+
+def decode_step(params, token: jax.Array, caches, pos: jax.Array,
+                cfg: ModelConfig, *, enc_kvs=None):
+    """One serving step: token (B, 1) at position ``pos`` (scalar int32).
+
+    Returns (logits (B, V), new caches).  No caller donates the caches:
+    ``launch/steps.py``'s jitted serve step and ``ProgressiveServer``'s
+    ``hidden_step`` each write the updated caches to new buffers.
+    """
+    x, new_caches, _ = decode_layers(params, token, caches, pos, cfg,
+                                     enc_kvs=enc_kvs)
+    logits = _lm_logits(params, x, cfg)
     return logits[:, 0, :], new_caches
 
 
@@ -613,11 +718,15 @@ def count_params(params) -> int:
 
 
 def active_params(cfg: ModelConfig, total: int) -> int:
-    """Active parameters per token (MoE: only top-k experts count)."""
+    """Active parameters per token (MoE: only top-k experts count; of a
+    held share, the share's expected top_k * held / num_experts)."""
     if cfg.family != "moe":
         return total
     m = cfg.moe
     per_expert = 3 * cfg.d_model * m.d_ff_expert
-    n_moe_layers = cfg.num_layers // m.interleave_step
-    inactive = per_expert * (m.num_experts - m.top_k) * n_moe_layers
+    n_moe_layers = sum(reps for unit, reps in block_groups(cfg)
+                       for kind in unit if kind == "moe")
+    held = m.held_experts[1] if m.held_experts else m.num_experts
+    inactive = (per_expert * n_moe_layers * held * (m.num_experts - m.top_k)
+                // m.num_experts)
     return total - inactive

@@ -59,7 +59,8 @@ __all__ = [
     "RETUNE", "TASK", "RESULT", "FUSED", "STALE", "HEARTBEAT", "RECONNECT",
     "DEAD", "QUARANTINE", "READMIT", "REDISPATCH", "REQUEST", "ADMIT",
     "RELEASE", "ARENA", "SERVE_PREFILL", "SERVE_DECODE", "STEP",
-    "HIDDEN_STEP", "HEAD", "COMPILE", "COMPILE_EVENTS", "span",
+    "HIDDEN_STEP", "HEAD", "COMPILE", "EXPERT_PAIRS", "COMPILE_EVENTS",
+    "span",
     "active_tracer", "profile_tracer", "serve_metrics",
     "worker_metrics_text",
 ]
@@ -122,13 +123,19 @@ HEAD = "head"              # span: dispatch of the step's LM head (the
 COMPILE = "compile"        # span: one JAX tracing, lowering, backend-
 #                            compile or compile-cache-read stage inside
 #                            a recorded span; label = the stage's event
+EXPERT_PAIRS = "expert_pairs"  # instant: the (token, expert) pairs a
+#                            decode step routed to one held expert of one
+#                            held-share MoE layer, copied when the step's
+#                            token is ready; round = step, task = MoE
+#                            layer, worker = held expert, value = pairs
 
 SPAN_KINDS = frozenset({PREP, ENCODE, ROUND, DECODE, JOB, TASK, REQUEST,
                         SERVE_PREFILL, SERVE_DECODE, STEP, HIDDEN_STEP,
                         HEAD, COMPILE})
 INSTANT_KINDS = frozenset({DISPATCH, RESOLUTION, RETUNE, RESULT, FUSED,
                            STALE, HEARTBEAT, RECONNECT, DEAD, QUARANTINE,
-                           READMIT, REDISPATCH, ADMIT, RELEASE, ARENA})
+                           READMIT, REDISPATCH, ADMIT, RELEASE, ARENA,
+                           EXPERT_PAIRS})
 EVENT_KINDS = SPAN_KINDS | INSTANT_KINDS
 
 

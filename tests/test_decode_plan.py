@@ -118,11 +118,14 @@ class TestDecodePlanCache:
 
     def test_mds_decode_through_plan(self):
         rng = np.random.default_rng(0)
-        mds = coding.MDSCode(k=3, n=5)
+        # plans are shared process-wide by geometry: one that no other
+        # test decodes (test_coding's MDS cases stop at n = k + 3), so
+        # the first decode of these ids is a miss whatever ran before
+        mds = coding.MDSCode(k=3, n=11)
         shards = rng.normal(size=(3, 6)).astype(np.float32)
         cw = np.asarray(mds.encode(shards))
         before = mds.plan().cache_info()["misses"]
-        ids = [4, 0, 2]
+        ids = [10, 0, 2]
         rec = np.asarray(mds.decode(ids, cw[np.asarray(ids)]))
         np.testing.assert_allclose(rec, shards, rtol=1e-3, atol=1e-4)
         assert mds.plan().cache_info()["misses"] == before + 1

@@ -56,7 +56,8 @@ def bits(x):
 
 # the yi-6b and starcoder2-7b smoke configurations are the two benchmark
 # cells' models at the tiny cells' widths; recurrentgemma-9b reads its
-# RG-LRU gate weights in float32, which the copy must leave alone
+# RG-LRU gate weights in float32, and deepseek-v2-lite its router, which
+# the copy must leave alone
 @pytest.mark.parametrize("arch", sorted(registry.ARCH_IDS))
 def test_served_bits_equal_the_masters(arch):
     cfg = registry.get_smoke_config(arch)
@@ -78,8 +79,9 @@ def test_served_bits_equal_the_masters(arch):
     tok, ref = first, []
     for i in range(G):
         pos = jnp.int32(P + i)
-        h_ref, ref_caches = server.hidden_step(params, tok, ref_caches, pos)
-        h, caches = server.hidden_step(server.params, tok, caches, pos)
+        h_ref, ref_caches = server.hidden_step(params, tok, ref_caches,
+                                               pos)[:2]
+        h, caches = server.hidden_step(server.params, tok, caches, pos)[:2]
         np.testing.assert_array_equal(bits(h), bits(h_ref))
         tok = jnp.argmax(server.head_series(h_ref)[-1], -1).astype(
             jnp.int32)[:, None]
@@ -155,3 +157,27 @@ def test_float32_compute_serves_the_masters():
     server = ProgressiveServer(cfg, params, m=3, d=5)
     assert server.compute_copy_bytes == 0
     assert server.params is params
+
+
+def test_copy_keeps_the_router_and_casts_latent_and_expert_matrices():
+    cfg = registry.get_smoke_config("deepseek-v2-lite")
+    params = masters(cfg)
+    server = ProgressiveServer(cfg, params, m=2, d=7)
+    dtypes = {jax.tree_util.keystr(p): x.dtype for p, x
+              in jax.tree_util.tree_flatten_with_path(server.params)[0]}
+    moe = "['groups'][1][0]"
+    assert dtypes[moe + "['ffn']['router']"] == jnp.float32
+    for leaf in ("['attn']['wq']", "['attn']['wkv_a']", "['attn']['wkv_b']",
+                 "['attn']['wo']", "['ffn']['we_gate']", "['ffn']['we_up']",
+                 "['ffn']['we_down']", "['ffn']['shared']['w_down']"):
+        assert dtypes[moe + leaf] == jnp.bfloat16, leaf
+    # norm gains, the latent's among them, stay float32
+    assert dtypes[moe + "['attn']['kv_norm']['scale']"] == jnp.float32
+    assert dtypes["['groups'][0][0]['ffn']['w_gate']"] == jnp.bfloat16
+    # the held-share layer reads the router in float32: no cast left
+    tokens, _ = inputs(cfg)
+    _, caches = server.prefill(tokens, P + G)
+    args = (tokens[:, -1:], caches, jnp.int32(P))
+    assert weight_casts(server.hidden_step, server.params, *args) == []
+    assert not any("router" in path for path in
+                   weight_casts(server.hidden_step, params, *args))
