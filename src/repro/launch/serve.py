@@ -25,6 +25,15 @@ The historical ``PlaneBudgetController`` (a serving-local EWMA deadline
 predictor) is gone: ``launch/serve.py`` no longer owns any deadline
 controller.
 
+The prefill is one jitted program, ``jit_prefill``, built with the
+server and compiled once per argument shape (batch, prompt length,
+``max_len``, the extra inputs' shapes); every later request of those
+shapes runs it without tracing or lowering anything.  Its name keeps it
+apart from the head's ``jit__lambda`` in the benchmark's trace reduction.
+Inside one program the TPU's compiler computes the LM head of
+``transformer.prefill`` at the last prompt position alone, the one it
+returns; run eagerly, or on the CPU, it computes every position.
+
 Each call and each decode step is a :func:`repro.runtime.telemetry.span`
 (``serve.prefill``, ``serve.decode``, ``serve.step``, ``serve.hidden_step``,
 ``serve.head``) on the profiler's timeline.  While one records (a tracer
@@ -226,7 +235,9 @@ class ProgressiveServer:
     model with held-share expert layers also the step's group sizes,
     ``(MoE layers, held experts)`` int32 (``transformer.decode_layers``);
     ``head_series(hidden)`` (jitted) is the on-chip head's ``m``
-    MSB-first resolutions, ``(m, B, V)`` float32.
+    MSB-first resolutions, ``(m, B, V)`` float32.  :meth:`prefill` runs
+    ``transformer.prefill`` as the jitted ``jit_prefill``, one program per
+    argument shape, with ``max_len`` static and the weights an argument.
 
     ``tracer`` records the serve path's spans without a profiler (see the
     module's docstring); each :meth:`prefill` begins a new request id
@@ -269,8 +280,13 @@ class ProgressiveServer:
             with jax.named_scope("hidden_step"):
                 return hidden(params, token, caches, pos)
 
-        # the programs' names (``jit_hidden_step``, ``jit__lambda``) are
-        # what the benchmark's trace reduction finds them by
+        def prefill(params, tokens, extras, max_len):
+            return T.prefill(params, tokens, cfg, max_len=max_len, **extras)
+
+        # the programs' names (``jit_prefill``, ``jit_hidden_step``,
+        # ``jit__lambda``) are what the benchmark's trace reduction finds
+        # them by
+        self._prefill = jax.jit(prefill, static_argnames="max_len")
         self.hidden_step = jax.jit(hidden_step)
         # the head's planes are an argument, not a closure: captured, they
         # would be baked into the program as constants (half a GB at a
@@ -295,8 +311,8 @@ class ProgressiveServer:
         self._job += 1
         with telemetry.span(telemetry.SERVE_PREFILL, "serve.prefill",
                             self.tracer, job=self._job):
-            return T.prefill(self.params, tokens, self.cfg,
-                             max_len=max_len, **extras)
+            return self._prefill(self.params, tokens, extras,
+                                 max_len=max_len)
 
     def _head(self, hidden, layer_budget, deadline_ms, stats: ServeStats):
         """The step's logits ``(B, V)`` and the resolution released."""

@@ -133,9 +133,12 @@ def test_given_tracer_records_without_profiler(model, budget):
 
 
 def test_programs_keep_their_names(model):
-    """The benchmark's trace reduction finds the two programs by name."""
+    """The benchmark's trace reduction finds the programs by name: the
+    prefill is not the head's ``jit__lambda``."""
     cfg, params, prompt = model
     server = ProgressiveServer(cfg, params, m=2, d=7)
+    pre = server._prefill.lower(server.params, prompt, {}, max_len=P + G)
+    assert "module @jit_prefill" in pre.as_text()
     _, caches = server.prefill(prompt, P + G)
     tok = prompt[:, -1:]
     step = server.hidden_step.lower(params, tok, caches, jnp.int32(P))
