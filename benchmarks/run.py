@@ -4,7 +4,6 @@ Sections:
   1. Paper figures 2a/2b/3a/3b (the faithful reproduction; claim checks)
   2. Coded-matmul throughput / erasure sweep
   3. Kernel micro-benches (interpret-mode exactness + jnp twin timing)
-  4. Roofline table from the dry-run artifacts (if results/dryrun exists)
 
 One CSV-ish block per paper table/figure, per the harness contract.
 """
@@ -23,7 +22,7 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="smaller job counts for CI-speed runs")
     ap.add_argument("--skip", default="",
-                    help="comma list: figures,coded,kernels,roofline")
+                    help="comma list: figures,coded,kernels")
     args = ap.parse_args()
     skip = set(args.skip.split(",")) if args.skip else set()
 
@@ -44,23 +43,6 @@ def main() -> None:
         print("# kernels (benchmarks/bench_kernels.py)")
         from benchmarks import bench_kernels
         bench_kernels.main()
-
-    if "roofline" not in skip:
-        print("#" * 72)
-        print("# roofline (benchmarks/roofline_table.py; source: dry-run)")
-        from benchmarks import roofline_table
-        if os.path.isdir(roofline_table.RESULTS):
-            try:
-                print(roofline_table.table("single"))
-                s = roofline_table.summary("single")
-                w = s["worst_fraction"]
-                print(f"\ncells: {s['num_cells']}; worst roofline fraction: "
-                      f"{w['arch']} x {w['shape']} "
-                      f"({w.get('roofline_fraction', 0):.4f})")
-            except Exception as e:  # empty dir mid-sweep etc.
-                print(f"(roofline table unavailable: {e})")
-        else:
-            print("(no results/dryrun — run python -m repro.launch.dryrun)")
 
 
 if __name__ == "__main__":

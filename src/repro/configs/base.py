@@ -32,7 +32,6 @@ class AttentionConfig:
     rope_theta: float = 10_000.0
     window: Optional[int] = None          # sliding-window size (local attn)
     causal: bool = True
-    qk_norm: bool = False
     attn_logit_softcap: Optional[float] = None
     # latent attention (DeepSeek-V2 MLA; kv_lora_rank 0 = none): keys and
     # values come from a cached (kv_lora_rank + qk_rope_head_dim) latent;
@@ -75,7 +74,6 @@ class MoEConfig:
     d_ff_expert: int                      # per-expert hidden size
     d_ff_shared: int = 0                  # shared-expert hidden size (0 = none)
     capacity_factor: float = 1.25
-    router_noise: float = 0.0
     interleave_step: int = 1              # every n-th layer is MoE (1 = all)
     dispatch_group: int = 4096            # tokens per dispatch group (GShard G)
     # 1 -> all layers MoE; 2 -> layers 1,3,5,... MoE (llama4-style)
@@ -135,13 +133,7 @@ class ModelConfig:
     param_dtype: str = "float32"
     kv_cache_dtype: str = ""              # "" = compute dtype; "int8" packs
     # the KV cache at 2x density (static scale; see models/transformer.py)
-    # scan/remat
     remat_policy: str = "minimal"         # none|minimal|full
-    scan_layers: bool = True
-    # layered-resolution serving (the paper's technique)
-    layered_lm_head: bool = False
-    layered_m: int = 2
-    layered_d: int = 7
 
     @property
     def sub_quadratic(self) -> bool:
@@ -185,15 +177,3 @@ class TrainConfig:
     grad_clip: float = 1.0
     b1: float = 0.9
     b2: float = 0.95
-    # coded data parallelism across pods (the paper's erasure story)
-    coded_dp: bool = False
-    coded_dp_k: int = 0                   # 0 -> n_pods (no redundancy)
-    # layered gradient all-reduce (beyond-paper)
-    layered_grad_planes: int = 0          # 0 = off
-    # cast fp32 master weights to compute dtype BEFORE use so FSDP
-    # all-gathers move bf16 (see EXPERIMENTS.md §Perf)
-    bf16_weight_gather: bool = False
-    # differentiate wrt the bf16 weight copy so the gradient
-    # reduce-scatter also moves bf16; grads are cast to fp32 after the
-    # reduction for the optimizer update
-    bf16_grads: bool = False

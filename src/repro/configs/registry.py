@@ -53,7 +53,7 @@ def shape_cells(cfg: ModelConfig) -> list[str]:
     """Applicable input-shape cells for this architecture.
 
     ``long_500k`` needs sub-quadratic sequence mixing — skipped for pure
-    full-attention archs (see DESIGN.md §Arch-applicability).  All ten archs
+    full-attention archs.  All ten archs
     bear a decoder, so decode shapes always apply.
     """
     cells = ["train_4k", "prefill_32k", "decode_32k"]

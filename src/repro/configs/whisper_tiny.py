@@ -3,7 +3,7 @@
 4 encoder + 4 decoder layers, d_model=384 6H (MHA) d_ff=1536 vocab=51865,
 layernorm + GELU.  The audio conv frontend is a STUB per the assignment:
 input_specs() provides precomputed frame embeddings (B, 1500, 384).
-Deviation noted in DESIGN.md: sinusoidal/rope positions instead of
+Deviation: sinusoidal/rope positions instead of
 Whisper's learned 448-position table so the assigned 4k/32k shapes lower.
 """
 import dataclasses

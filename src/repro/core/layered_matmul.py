@@ -1,6 +1,6 @@
 """Executable layered + coded matmul pipeline, and coded data-parallelism.
 
-Three levels, mirroring DESIGN.md §3:
+Two levels:
 
 1. :class:`LayeredCodedMatmul` — the paper end-to-end: quantize operands,
    digit-decompose (``repro.core.layering``), iterate mini-jobs MSB-first,
@@ -9,12 +9,7 @@ Three levels, mirroring DESIGN.md §3:
    survivors, and accumulate resolutions.  This is the reference system the
    simulator models in time and the quickstart example runs.
 
-2. :func:`distributed_layered_matmul` — a `shard_map` execution of the coded
-   tasks across a device mesh axis: each device computes its slice of the
-   codeword batch; the fusion is a gather + host decode.  Lowerable on the
-   production mesh (exercised by the dry-run).
-
-3. :class:`GradientCoder` — MDS-coded data parallelism across pods: each pod
+2. :class:`GradientCoder` — MDS-coded data parallelism across pods: each pod
    contributes a linear combination of gradient shards; any ``k`` of ``n``
    pod codewords decode the full-batch gradient (pod loss = erasure).  The
    decode weights for a surviving subset collapse to a single per-pod scalar,
@@ -30,13 +25,10 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import coding, layering
 
-__all__ = [
-    "LayeredCodedMatmul", "distributed_layered_matmul", "GradientCoder",
-]
+__all__ = ["LayeredCodedMatmul", "GradientCoder"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,52 +142,6 @@ class LayeredCodedMatmul:
         results = code.compute_all_tasks(X, Y)
         ids = survivors[: code.k]
         return code.decode(ids, np.asarray(results)[np.asarray(ids)])
-
-
-# ---------------------------------------------------------------------------
-# shard_map distributed execution of the coded tasks
-# ---------------------------------------------------------------------------
-
-def distributed_layered_matmul(mesh: Mesh, axis: str, a: jax.Array,
-                               b: jax.Array, *, m: int, d: int,
-                               n1: int, n2: int, omega: float):
-    """Compute coded task results for every mini-job, sharded over ``axis``.
-
-    Encoding happens once (replicated); each device multiplies its slice of
-    the codeword batch; results are all-gathered so any host can decode from
-    the first k arrivals.  Returns (task_results, layer_index) where
-    ``task_results`` has shape (m*m, T, M/n1, N/n2) laid out mini-job-major
-    in MSB-first execution order.
-    """
-    code = coding.PolynomialCode(n1=n1, n2=n2, omega=omega, mode="float")
-    T = code.num_tasks
-    naxis = mesh.shape[axis]
-    if T % naxis:
-        # pad codeword count to the axis size; extra tasks are pure redundancy
-        T = ((T // naxis) + 1) * naxis
-        code = dataclasses.replace(code, omega=T / code.k)
-
-    ca = layering.decompose(a.astype(jnp.int32), m, d).astype(jnp.float32)
-    cb = layering.decompose(b.astype(jnp.int32), m, d).astype(jnp.float32)
-    order = layering.all_minijobs_msb_first(m)
-
-    Xs, Ys = [], []
-    for (_, i, j) in order:
-        X, Y = code.encode(ca[i], cb[j])
-        Xs.append(X)
-        Ys.append(Y)
-    X = jnp.stack(Xs)  # (m*m, T, K, M/n1)
-    Y = jnp.stack(Ys)  # (m*m, T, K, N/n2)
-
-    def worker(x_blk, y_blk):
-        # x_blk: (m*m, T/naxis, K, M/n1) local codeword slice
-        local = jnp.einsum("qtkm,qtkn->qtmn", x_blk, y_blk)
-        return jax.lax.all_gather(local, axis, axis=1, tiled=True)
-
-    fn = jax.shard_map(worker, mesh=mesh,
-                       in_specs=(P(None, axis), P(None, axis)),
-                       out_specs=P(None, None))
-    return fn(X, Y), [l for (l, _, _) in order]
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,10 @@
 """Progressive-precision (layered) linear layers for deadline-bounded serving.
 
-The paper's layered resolution, applied on-chip (DESIGN.md §3.1): weights
-(and optionally activations) are digit-decomposed; computing digit planes
-MSB-first means a valid approximate output exists after every plane — a
-server hitting its deadline releases the best available resolution instead
-of nothing.
+The paper's layered resolution (§III; docs/paper-map.md), applied on-chip:
+weights (and optionally activations) are digit-decomposed; computing digit
+planes MSB-first means a valid approximate output exists after every
+plane — a server hitting its deadline releases the best available
+resolution instead of nothing.
 
 Two modes:
 
@@ -14,7 +14,7 @@ Two modes:
 * ``two-sided`` (paper-faithful): both x and W are quantized and decomposed;
   mini-jobs follow Definition 1's anti-diagonals — ``2m-1`` resolutions.
 
-`layered_lm_head` wires the weight-only mode into an LM's final projection,
+``launch/serve.py`` serves an LM's final projection in the weight-only mode:
 the serving hot-spot where vocab-size matmuls dominate decode latency.
 """
 
@@ -142,8 +142,3 @@ def two_sided_layered_matmul(x: jax.Array, w: jax.Array, *, m: int, d: int):
     scale = (sx * sw).astype(jnp.float32)
     return jnp.stack(outs, axis=0) * scale
 
-
-def layered_lm_head(params: LayeredLinear, hidden: jax.Array,
-                    resolution: Optional[int] = None) -> jax.Array:
-    """Progressive LM-head logits at the requested resolution."""
-    return layered_linear_apply(params, hidden, resolution)

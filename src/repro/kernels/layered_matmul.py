@@ -1,7 +1,7 @@
 """Pallas TPU kernel: layered-resolution int8 matmul (the paper's mini-job
 grid as ONE fused MXU pass).
 
-TPU-native rethinking of §III (DESIGN.md §6): instead of shipping the m**2
+TPU-native rethinking of §III: instead of shipping the m**2
 digit-plane mini-jobs ``A_i^T B_j`` to separate workers, the kernel walks
 the anti-diagonals **MSB-first inside the systolic array's dataflow**: for
 each (M, N) output tile it accumulates the plane-pair products layer by

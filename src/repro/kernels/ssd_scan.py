@@ -2,10 +2,9 @@
 
 The jnp SSD path (models/ssm.py) materialises per-chunk (l x l) decay and
 score matrices plus per-chunk states in HBM — on the mamba2 cells the
-memory term, not compute, is the post-collective bottleneck
-(EXPERIMENTS.md cell B).  This kernel keeps everything per-(batch, head)
-in VMEM: the running (P x N) state lives in scratch across the chunk grid
-dimension, and each grid step fuses
+memory term, not compute, is the post-collective bottleneck.  This kernel
+keeps everything per-(batch, head) in VMEM: the running (P x N) state lives
+in scratch across the chunk grid dimension, and each grid step fuses
 
     intra:  y_d = (C B^T  ∘  L) · (dt x)          (l x l on the MXU)
     carry:  y_o = (C · state^T) ∘ exp(acum)

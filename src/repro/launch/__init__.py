@@ -1,7 +1,3 @@
-"""Launch layer: meshes, sharding rules, step builders, dry-run, drivers.
+"""Launch layer: meshes, sharding rules, step builders, drivers."""
 
-NOTE: repro.launch.dryrun sets XLA_FLAGS at import time (512 host devices);
-import it only as an entry point, never from library code.
-"""
-
-from repro.launch import mesh, roofline, sharding  # noqa: F401
+from repro.launch import mesh, sharding  # noqa: F401

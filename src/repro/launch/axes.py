@@ -28,46 +28,28 @@ __all__ = ["constrain", "mesh_context", "current_mesh"]
 
 _STATE = threading.local()
 
-# sharding profiles (see launch/sharding.py):
-#   "tp_fsdp" (default): batch over (pod, data); TP over model; FSDP data
-#   "fsdp":   batch over (pod, data, model); no activation TP (pure ZeRO-3)
-#   "serve":  like tp_fsdp for activations; params keep TP but drop FSDP
-_PROFILES = {
-    "tp_fsdp": {"batch": ("pod", "data"), "fsdp": ("data",),
-                "tp": ("model",)},
-    "fsdp": {"batch": ("pod", "data", "model"), "fsdp": ("data",),
-             "tp": ()},
-    "serve": {"batch": ("pod", "data"), "fsdp": ("data",),
-              "tp": ("model",)},
-}
+# logical axis -> mesh axes: batch over (pod, data); TP over model; FSDP data
+_LOGICAL = {"batch": ("pod", "data"), "fsdp": ("data",), "tp": ("model",)}
 
 
 def current_mesh() -> Optional[Mesh]:
     return getattr(_STATE, "mesh", None)
 
 
-def current_profile() -> str:
-    return getattr(_STATE, "profile", "tp_fsdp")
-
-
 @contextlib.contextmanager
-def mesh_context(mesh: Optional[Mesh], profile: str = "tp_fsdp"):
+def mesh_context(mesh: Optional[Mesh]):
     prev = current_mesh()
-    prev_prof = current_profile()
     _STATE.mesh = mesh
-    _STATE.profile = profile
     try:
         yield
     finally:
         _STATE.mesh = prev
-        _STATE.profile = prev_prof
 
 
 def _resolve(axis, mesh: Mesh):
     if axis is None:
         return None
-    logical = _PROFILES[current_profile()]
-    names = logical.get(axis, (axis,))
+    names = _LOGICAL.get(axis, (axis,))
     present = tuple(a for a in names if a in mesh.axis_names)
     if not present:
         return None

@@ -6,9 +6,9 @@
    re-places every leaf with the sharding rules evaluated on the *current*
    mesh, so a job that lost a pod restarts on the remaining pods (or a
    resized slice) without conversion tooling.
-3. **Coded data parallelism** — the paper's erasure story at pod granularity
-   (DESIGN.md §3.2): with n pods and redundancy n/k, each pod computes the
-   gradient of an MDS-coded combination of data shards
+3. **Coded data parallelism** — the paper's erasure story at pod granularity:
+   with n pods and redundancy n/k, each pod computes the gradient of an
+   MDS-coded combination of data shards
    (``repro.core.layered_matmul.GradientCoder``).  If a pod is lost mid-step
    (preemption, network partition), the fusion decodes the full-batch
    gradient from any k surviving pod codewords — one weighted psum, no

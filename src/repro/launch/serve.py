@@ -1,6 +1,6 @@
 """Batched serving driver with deadline-bounded progressive resolution.
 
-The paper's §IV deadline experiment at the LM-head (DESIGN.md §3.1):
+The paper's §IV deadline experiment at the LM-head (docs/paper-map.md):
 each decode step has a budget, logits are produced resolution-by-
 resolution MSB-first, and when the budget expires the server releases
 the best resolution computed so far instead of nothing.

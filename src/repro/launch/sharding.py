@@ -13,8 +13,7 @@ Rules are keyed on the *leaf name* (the last key in the parameter path) and
 the leaf's rank, so they apply uniformly to every architecture in the zoo.
 pjit rejects non-divisible argument shardings, so ``fix_spec`` relocates a
 mesh axis to a dividing dim (8 KV heads can't split 16 ways -> shard
-head_dim instead) or drops it; every fallback is visible in the dry-run's
-sharding dump.
+head_dim instead) or drops it.
 """
 
 from __future__ import annotations
@@ -114,7 +113,7 @@ def fix_spec(shape: tuple, spec: tuple, mesh: Mesh, *,
     earlier) unassigned dim that does divide — e.g. 8 KV heads cannot shard
     over a 16-way "model" axis, but head_dim=128 can, so
     (..., "model", None) becomes (..., None, "model").  If no dim fits, the
-    axis is dropped (replicated) — visible honestly in the roofline.
+    axis is dropped (replicated).
     """
     spec = tuple(spec)[: len(shape)]
     spec = spec + (None,) * (len(shape) - len(spec))
@@ -159,19 +158,10 @@ def _spec_for(path, leaf, mesh: Mesh) -> P:
     return P()  # replicate anything unmatched (scalars, counters, ...)
 
 
-def param_specs(params_shapes: Any, mesh: Mesh,
-                profile: str = "tp_fsdp") -> Any:
-    """PartitionSpec pytree matching a params (shape) pytree.
-
-    profile "serve" drops the FSDP axis (weights stay TP-sharded,
-    replicated over data): serving must not re-gather weights per token.
-    """
+def param_specs(params_shapes: Any, mesh: Mesh) -> Any:
+    """PartitionSpec pytree matching a params (shape) pytree."""
     flat, treedef = jax.tree.flatten_with_path(params_shapes)
-    specs = [_spec_for(p, l, mesh) for p, l in flat]
-    if profile == "serve":
-        specs = [P(*(None if ax == _FSDP else ax for ax in tuple(sp)))
-                 for sp in specs]
-    return treedef.unflatten(specs)
+    return treedef.unflatten([_spec_for(p, l, mesh) for p, l in flat])
 
 
 def opt_state_specs(opt_shapes: Any, pspecs: Any, mesh: Mesh) -> Any:
@@ -221,13 +211,9 @@ def _leaf_name_seq(path) -> list[str]:
     return [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
 
 
-def batch_specs(batch_shapes: Any, mesh: Mesh,
-                profile: str = "tp_fsdp") -> Any:
+def batch_specs(batch_shapes: Any, mesh: Mesh) -> Any:
     """Shard dim 0 of every batch leaf over the batch axes; scalars replicate."""
     baxes = batch_axes(mesh)
-    if profile == "fsdp":  # pure-DP: the model axis also carries batch
-        baxes = tuple(a for a in ("pod", "data", "model")
-                      if a in mesh.axis_names)
 
     def spec(leaf):
         shape = _shape_of(leaf)

@@ -1,10 +1,9 @@
 """Step functions (train / prefill / serve) and their sharded lowering.
 
-``build_cell`` is the single entry point the dry-run, the roofline pass and
-the drivers share: given (arch config, shape cell, mesh) it constructs the
-step function, the ShapeDtypeStruct inputs, and the in/out shardings, and
-returns a ``jax.jit``-wrapped callable ready to ``.lower()`` (dry-run) or
-execute (CPU-scale smoke/train).
+``build_cell`` is the entry point the training driver and the tests share:
+given (arch config, shape cell, mesh) it constructs the step function, the
+ShapeDtypeStruct inputs, and the in/out shardings, and returns a
+``jax.jit``-wrapped callable ready to ``.lower()`` or execute.
 """
 
 from __future__ import annotations
@@ -30,30 +29,11 @@ __all__ = ["make_train_step", "make_prefill_step", "make_serve_step",
 _QCHUNK = 1024  # query-block size for chunked attention (prefill & train)
 
 
-def _cast_for_compute(params, cfg: ModelConfig):
-    """fp32 master -> compute-dtype copy BEFORE use, so FSDP all-gathers
-    move bf16 (half the wire bytes).  Only weight matrices are cast
-    (ndim >= 3 under scanned groups, plus embed/lm_head); fp32-sensitive
-    1-2D leaves (A_log, dt_bias, Lambda, norm scales) stay fp32."""
-    cd = cfg.cdtype()
-
-    def leaf(path, x):
-        name = str(getattr(path[-1], "key", getattr(path[-1], "idx", "")))
-        if x.dtype == jnp.float32 and (x.ndim >= 3
-                                       or name in ("embed", "lm_head")):
-            return x.astype(cd)
-        return x
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig):
     optimizer = make_optimizer(tcfg)
 
     def train_step(params, opt_state, batch):
         def loss_fn(p):
-            if tcfg.bf16_weight_gather and not tcfg.bf16_grads:
-                p = _cast_for_compute(p, cfg)
             loss, metrics = T.forward_train(
                 p, batch["tokens"], batch["targets"], cfg,
                 extra_embeds=batch.get("extra_embeds"),
@@ -61,16 +41,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig):
                 q_chunk=_QCHUNK)
             return loss, metrics
 
-        if tcfg.bf16_grads:
-            # differentiate wrt the bf16 copy: the data-parallel gradient
-            # reduce-scatter then moves bf16; cast up AFTER the reduction
-            params_c = _cast_for_compute(params, cfg)
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params_c)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        else:
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
+        (loss, metrics), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params)
         new_params, new_opt = optimizer.update(grads, opt_state, params)
         metrics = dict(metrics)
         metrics["grad_norm"] = new_opt.get("gnorm", jnp.float32(0))
@@ -126,20 +98,19 @@ def _abstract_params(cfg: ModelConfig):
 
 
 def build_cell(cfg: ModelConfig, shape: ShapeConfig | str, mesh: Mesh,
-               tcfg: Optional[TrainConfig] = None,
-               profile: str = "tp_fsdp") -> Cell:
+               tcfg: Optional[TrainConfig] = None) -> Cell:
     if isinstance(shape, str):
         shape = SHAPES[shape]
     kind, batch_shapes = registry.input_specs(cfg, shape)
     params_shapes = _abstract_params(cfg)
-    pspecs = sh.param_specs(params_shapes, mesh, profile)
+    pspecs = sh.param_specs(params_shapes, mesh)
 
     if kind == "train":
         tcfg = tcfg or TrainConfig()
         step, optimizer = make_train_step(cfg, tcfg)
         opt_shapes = jax.eval_shape(optimizer.init, params_shapes)
         ospecs = sh.opt_state_specs(opt_shapes, pspecs, mesh)
-        bspecs = sh.batch_specs(batch_shapes, mesh, profile)
+        bspecs = sh.batch_specs(batch_shapes, mesh)
         arg_shapes = (params_shapes, opt_shapes, batch_shapes)
         in_specs = (pspecs, ospecs, bspecs)
         # output opt_state grows scalar entries (gnorm/lr) -> respecify
@@ -184,7 +155,7 @@ def build_cell(cfg: ModelConfig, shape: ShapeConfig | str, mesh: Mesh,
     def step_in_mesh(*args, _step=step):
         # activation sharding constraints (launch/axes.py) need the ambient
         # mesh DURING tracing, which happens lazily inside jit
-        with mesh_context(mesh, profile):
+        with mesh_context(mesh):
             return _step(*args)
 
     jitted = jax.jit(step_in_mesh, in_shardings=named_in,

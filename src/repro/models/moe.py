@@ -23,9 +23,6 @@ capacity-based MoE.
 
 A shared expert (Qwen2-MoE: 4x1408 fused; Llama4: one 8192) runs densely
 alongside the routed experts.
-
-The expert-parallel all-to-all alternative is explored in the perf
-hillclimb (EXPERIMENTS.md §Perf).
 """
 
 from __future__ import annotations

@@ -15,7 +15,7 @@ with the RG-LRU recurrence (all elementwise, diagonal):
 
 Train/prefill evaluates the diagonal linear recurrence with
 ``jax.lax.associative_scan`` (log-depth, TPU-friendly — the GPU kernel's
-sequential fused scan does not transfer; see DESIGN.md).  Decode carries
+sequential fused scan does not transfer).  Decode carries
 ``h`` directly: O(D_rnn) per token, so recurrentgemma runs ``long_500k``
 (its attention layers are sliding-window, cache bounded by the window).
 """

@@ -5,7 +5,7 @@ the sequence is split into chunks of ``chunk_size``; each chunk computes a
 dense intra-chunk (quadratic-in-chunk) term plus an inter-chunk linear
 recurrence over per-chunk states — O(S) total with matmul-friendly inner
 shapes (this is the TPU-appropriate formulation; the CUDA kernel's
-warp-level scan does not transfer, per DESIGN.md hardware-adaptation notes).
+warp-level scan does not transfer).
 
 Decode keeps a recurrent state (B, H, P, N) plus a (d_conv-1)-deep causal
 conv cache; one token costs O(H*P*N) — sequence-length-independent, which is

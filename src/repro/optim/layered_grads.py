@@ -1,6 +1,6 @@
 """Layered gradient all-reduce — the paper's resolution layers on collectives.
 
-Beyond-paper application (DESIGN.md §3.3): gradients are quantized and
+Beyond-paper application: gradients are quantized and
 digit-decomposed (``repro.core.layering``); the all-reduce then runs
 **MSB-plane-first**.  A deadline-bounded synchronous step can apply the
 optimizer update from the first plane(s) and feed the unsent remainder back

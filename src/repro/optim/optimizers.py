@@ -4,7 +4,7 @@ Self-contained (no optax in this environment).  Both optimizers follow the
 ``init(params) -> state`` / ``update(grads, state, params) -> (params',
 state')`` interface and keep fp32 master weights regardless of the compute
 dtype; the 400B llama4 config defaults to Adafactor so the optimizer state
-fits the single-pod memory budget (see EXPERIMENTS.md §Dry-run).
+fits the single-pod memory budget (``test_memory_estimate_fits_hbm``).
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests see 1 CPU device;
-only launch/dryrun.py forces 512 host devices (per the brief)."""
+meshes of production shape are checked as fake meshes (no devices)."""
 
 import numpy as np
 import pytest

@@ -1,12 +1,11 @@
-"""Integration tests: end-to-end training learns; serving profiles; the
-int8 KV cache; train-loop checkpoint/resume."""
+"""Integration tests: end-to-end training learns; the sharded train cell
+runs; the int8 KV cache; train-loop checkpoint/resume."""
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs import registry
 from repro.configs.base import (AttentionConfig, ModelConfig, ShapeConfig,
@@ -50,14 +49,11 @@ class TestTrainingLearns:
 
 
 class TestShardingProfiles:
-    @pytest.mark.parametrize("profile", ["tp_fsdp", "fsdp", "serve"])
-    def test_profiles_lower_and_run(self, profile):
+    def test_profiles_lower_and_run(self):
         cfg = tiny_cfg()
         mesh = make_test_mesh(1, 1)
         cell = steps_lib.build_cell(cfg, ShapeConfig("t", 32, 2, "train"),
-                                    mesh, TrainConfig(bf16_weight_gather=True,
-                                                      bf16_grads=True),
-                                    profile=profile)
+                                    mesh, TrainConfig())
         params = T.init_params(jax.random.PRNGKey(0), cfg)
         _, opt = steps_lib.make_train_step(cfg, TrainConfig())
         state = opt.init(params)
@@ -65,23 +61,6 @@ class TestShardingProfiles:
                  "targets": jnp.zeros((2, 32), jnp.int32)}
         p2, s2, m = cell.fn(params, state, batch)
         assert np.isfinite(float(m["loss"]))
-
-    def test_serve_profile_drops_fsdp_axis(self):
-        from jax.sharding import PartitionSpec as P
-
-        from repro.launch import sharding as sh
-
-        class FakeMesh:
-            shape = {"data": 16, "model": 16}
-            axis_names = ("data", "model")
-
-        shapes = jax.eval_shape(
-            lambda: {"w_gate": jnp.zeros((2, 4096, 14336))})
-        tp = sh.param_specs(shapes, FakeMesh())
-        srv = sh.param_specs(shapes, FakeMesh(), profile="serve")
-        assert "data" in str(tp["w_gate"])
-        assert "data" not in str(srv["w_gate"])
-        assert "model" in str(srv["w_gate"])
 
 
 class TestInt8KVCache:
